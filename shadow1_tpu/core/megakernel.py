@@ -45,11 +45,13 @@ mesh already uses: block b of a shard at offset `base` runs with
 hoff = base + b * block_hosts, so RNG keys, packet SRC columns, and
 host_vertex slicing see global ids.
 
-On TPU the kernels lower through Mosaic; on every other backend they run
-in Pallas interpret mode, so CPU tests exercise the same code path
-(`docs/megakernel.md` has the full contract).  The flag is static
-(params.megakernel, in ShapeKey), so buckets never mix fused and
-reference graphs.
+The path is off by default (params.megakernel=False): on a TPU the
+kernels do not lower through Mosaic yet (FusedPathUnavailable says why),
+so asking for them there raises at trace time, and off the TPU they run
+in Pallas interpret mode, which is how the CPU tests pin them bitwise
+against the reference (`docs/megakernel.md` has the full contract).  The
+flag is static (params.megakernel, in ShapeKey), so buckets never mix
+fused and reference graphs.
 """
 
 from __future__ import annotations
@@ -74,6 +76,26 @@ _PARAMS_REP = ("route_blk", "host_vertex", "min_latency_ns", "seed_key",
                "cpu_precision_ns", "qdisc")
 
 
+class FusedPathUnavailable(RuntimeError):
+    """The fused kernels were asked for on a backend whose Pallas
+    lowering refuses them (the TPU's Mosaic compiler)."""
+
+
+_MOSAIC_REFUSAL = (
+    "params.megakernel=True asks for the fused Pallas kernels, and the "
+    "TPU's Mosaic compiler refuses every one of them: K_WINDOW with "
+    "'Only arrays with 32-bit element types can be converted to scalars, "
+    "but got: float64' (kernel bodies carry int64 simulated time and "
+    "float64 values; Mosaic lowers 32-bit element types only, and a "
+    "kernel on an int64 block is UNIMPLEMENTED), K_DELIVER/K_TRANSPORT "
+    "for rank-1 host blocks that are not a multiple of the 128-lane "
+    "tiling, and the exchange kernel with a RecursionError in Mosaic's "
+    "convert_element_type lowering (its core also sorts, which has no "
+    "Pallas TPU lowering).  Run with megakernel=False (the default): the "
+    "reference XLA graph compiles and runs on the chip "
+    "(docs/megakernel.md, 'On the TPU').")
+
+
 def enabled(state: SimState, params, app) -> bool:
     """Trace-time static: does this world take the fused path?  The
     log/capture rings and the lineage span ring append at global cursors
@@ -87,6 +109,8 @@ def enabled(state: SimState, params, app) -> bool:
     battery (docs/megakernel.md, "What gates and what doesn't")."""
     if not getattr(params, "megakernel", False):
         return False
+    if not _interpret():
+        raise FusedPathUnavailable(_MOSAIC_REFUSAL)
     return state.log is None and state.cap is None \
         and state.lineage is None
 
@@ -106,6 +130,9 @@ def persistent_enabled(state: SimState, params, app) -> bool:
 
 
 def _interpret() -> bool:
+    """Interpret mode everywhere but the TPU, where enabled() refuses the
+    fused path before any kernel is traced: no kernel ever runs
+    interpreted on the chip."""
     return jax.default_backend() != "tpu"
 
 

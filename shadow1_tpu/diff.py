@@ -412,7 +412,17 @@ def localize_elements(dir_a: str, dir_b: str, stream: dict, *,
     target = min(int(div["t_end"]["a"]), int(div["t_end"]["b"]))
     a = _reexec(dir_a, anchor_w, target, devices=devices)
     b = _reexec(dir_b, anchor_w, target, devices=devices)
-    sa, sb = a["state"], b["state"]
+    return {"anchor": {"a": a["anchor"], "b": b["anchor"]},
+            "target_ns": target,
+            **compare_states(a["state"], b["state"], div["group"],
+                             max_elements=max_elements)}
+
+
+def compare_states(sa, sb, first_group=None, *,
+                   max_elements: int = 8) -> dict:
+    """Element-compare two host-side states of one world at one sim
+    time, digest field group by group (`first_group` first): the groups
+    that differ and each differing field's element report."""
     h = int(sa.hosts.num_hosts)
     if int(sb.hosts.num_hosts) != h:
         raise DiffUsageError(
@@ -425,9 +435,9 @@ def localize_elements(dir_a: str, dir_b: str, stream: dict, *,
     # root divergence usually fans out into several groups by the end
     # of the window).
     order = list(DIGEST_GROUPS)
-    if div["group"] in order:
-        order.remove(div["group"])
-        order.insert(0, div["group"])
+    if first_group in order:
+        order.remove(first_group)
+        order.insert(0, first_group)
     fields = []
     groups_differing = []
     for g in order:
@@ -440,10 +450,7 @@ def localize_elements(dir_a: str, dir_b: str, stream: dict, *,
                 hit = True
         if hit:
             groups_differing.append(g)
-    return {"anchor": {"a": a["anchor"], "b": b["anchor"]},
-            "target_ns": target,
-            "groups_differing": groups_differing,
-            "fields": fields}
+    return {"groups_differing": groups_differing, "fields": fields}
 
 
 def diff_runs(dir_a: str, dir_b: str, *, localize: bool = True,

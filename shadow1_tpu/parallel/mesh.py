@@ -5,7 +5,7 @@ input shardings -- fine for correctness, but the compiler re-derives the
 communication pattern of the boundary exchange from a scatter into a
 fully-sharded inbox, and the loop-carried reductions get re-partitioned
 per iteration.  `mesh_run_until` instead runs the engine's window loop
-INSIDE `jax.experimental.shard_map.shard_map` on a 1-D `hosts` mesh with
+INSIDE `jax.shard_map` on a 1-D `hosts` mesh with
 hand-placed collectives, mirroring the reference's explicit scheduler
 protocol (/root/reference/src/main/core/scheduler/scheduler.c:359-414):
 
@@ -34,8 +34,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core import engine
 from .sharding import (HOST_AXIS, PARAM_SPECS, _leaf_name, make_mesh,
@@ -126,7 +125,7 @@ def _build(app, mesh, sspecs, pspecs):
 
         # Finalize cross-shard aggregates so every shard returns the
         # IDENTICAL value for every replicated leaf (out_specs P() with
-        # check_rep=False trusts, but does not create, replication):
+        # check_vma=False trusts, but does not create, replication):
         # counters entered replicated, so global = start + psum(delta);
         # err is a bitmask -> all_gather + OR (psum would double-count
         # bits, pmax would drop them).  now/n_steps/n_windows/exchanges
@@ -147,16 +146,27 @@ def _build(app, mesh, sspecs, pspecs):
             st = st.replace(tr=st.tr.replace(
                 pkts_exchanged=tr0.pkts_exchanged + jax.lax.psum(
                     st.tr.pkts_exchanged - tr0.pkts_exchanged, HOST_AXIS),
-                occ_max=jax.lax.pmax(st.tr.occ_max, HOST_AXIS)))
+                occ_max=engine.mesh_max(st.tr.occ_max)))
         if ln0 is not None:
             st = st.replace(lineage=st.lineage.replace(
                 n_assigned=ln0 + jax.lax.psum(
                     st.lineage.n_assigned - ln0, HOST_AXIS)))
         return st.replace(hoff=None)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(sspecs, pspecs, P()),
-        out_specs=sspecs, check_rep=False))
+        out_specs=sspecs, check_vma=False))
+
+
+def _place(mesh, tree, specs):
+    """Lay `tree` out as a shard_map body with these in_specs expects.
+    A world assembled on an accelerator arrives committed to
+    jax.devices()[0] (shadow1_tpu.build_on_host), which jit refuses to
+    mix with a multi-device mesh; leaves already laid out (the outputs
+    of an earlier launch) do not move."""
+    return jax.device_put(tree, jax.tree_util.tree_map(
+        lambda spec: NamedSharding(mesh, spec), specs,
+        is_leaf=lambda x: isinstance(x, P)))
 
 
 def mesh_run_until(state, params, app, t_target, mesh=None):
@@ -239,6 +249,7 @@ def mesh_run_until(state, params, app, t_target, mesh=None):
     if fn is None:
         fn = _build(app, mesh, sspecs, pspecs)
         _MESH_CACHE[key] = fn
+    state, params = _place(mesh, (state, params), (sspecs, pspecs))
     with mesh:
         return fn(state, params, jnp.asarray(t_target, I64))
 
@@ -286,8 +297,9 @@ def exchange_probe_ms(state, params, mesh, reps: int = 5) -> float:
         st = engine._exchange_body_mesh(st.replace(hoff=hoff), pr)
         return st.replace(hoff=None)
 
-    fn = jax.jit(shard_map(body, mesh=mesh, in_specs=(sspecs, pspecs),
-                           out_specs=sspecs, check_rep=False))
+    fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(sspecs, pspecs),
+                               out_specs=sspecs, check_vma=False))
+    state, params = _place(mesh, (state, params), (sspecs, pspecs))
     with mesh:
         jax.block_until_ready(fn(state, params))   # compile + warm
         times = []

@@ -7,34 +7,20 @@ between Packet metadata and the shared refcounted Payload
 storage layer the real-code substrate will feed (app write() bytes in,
 recv() bytes out).
 
-The shared library builds on demand with g++ into
-`native/build/` (cached by source mtime); ctypes binds the C ABI --
-pybind11 is not part of this toolchain.
+The shared library builds on demand with g++ through
+`substrate.buildlib` (cached by source hash under `native/build/`);
+ctypes binds the C ABI -- pybind11 is not part of this toolchain.
 """
 
 from __future__ import annotations
 
 import ctypes
-import os
-import subprocess
+import pathlib
 
-_NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "native")
-_SRC = os.path.join(_NATIVE_DIR, "payload_arena.cc")
-_BUILD_DIR = os.path.join(_NATIVE_DIR, "build")
-_LIB = os.path.join(_BUILD_DIR, "libpayload_arena.so")
+from .substrate import buildlib
 
-
-def _ensure_built() -> str:
-    if (not os.path.exists(_LIB)
-            or os.path.getmtime(_LIB) < os.path.getmtime(_SRC)):
-        os.makedirs(_BUILD_DIR, exist_ok=True)
-        subprocess.run(
-            ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-o", _LIB,
-             _SRC],
-            check=True, capture_output=True, text=True)
-    return _LIB
-
+_SRC = pathlib.Path(__file__).resolve().parents[1] / "native" / \
+    "payload_arena.cc"
 
 _lib = None
 
@@ -42,7 +28,8 @@ _lib = None
 def _load():
     global _lib
     if _lib is None:
-        lib = ctypes.CDLL(_ensure_built())
+        lib = ctypes.CDLL(buildlib.build_lib(
+            _SRC, "libpayload_arena", "g++", ["-std=c++17"]))
         lib.payload_arena_create.restype = ctypes.c_void_p
         lib.payload_arena_destroy.argtypes = [ctypes.c_void_p]
         lib.payload_arena_put.restype = ctypes.c_uint64

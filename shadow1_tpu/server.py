@@ -646,16 +646,20 @@ class Server:
 
     def _sync_request(self, req: Request) -> None:
         """Mirror the full record atomically to runs/<id>/request.json
-        (tmp + rename -- never torn, like every other state file)."""
+        (tmp + rename -- never torn, like every other state file).  The
+        connection handler and the worker both mirror a request, so the
+        write and the rename happen under the lock: unlocked, one
+        thread's rename moved the other's tmp away, and the worker died
+        on the FileNotFoundError with the request never settled."""
         d = os.path.join(self.runs_dir, req.id)
         os.makedirs(d, exist_ok=True)
         path = os.path.join(d, "request.json")
         tmp = path + ".tmp"
         with self._lock:
             rec = req.record(d)
-        with open(tmp, "w") as f:
-            json.dump(rec, f, indent=1, sort_keys=True)
-        os.replace(tmp, path)
+            with open(tmp, "w") as f:
+                json.dump(rec, f, indent=1, sort_keys=True)
+            os.replace(tmp, path)
 
     # -- socket side ------------------------------------------------------
 

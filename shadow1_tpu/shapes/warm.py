@@ -1,8 +1,8 @@
 """AOT warm cache: pre-compile the standard bucket set.
 
 The package enables JAX's persistent compilation cache at import
-(shadow1_tpu/__init__.py: SHADOW1_TPU_CACHE, default
-~/.cache/shadow1_tpu_xla).  `warm_buckets` builds one canonical world
+(shadow1_tpu/__init__.py: JAX_COMPILATION_CACHE_DIR where set, else
+`.jax_cache/` in the checkout).  `warm_buckets` builds one canonical world
 per (app flavor, host bucket), pads it into its bucket
 (pad_world_to_bucket -- so the compiled graph is the SHARED one every
 bucketed world of that shape hits, hosts_real included), and AOT
@@ -109,27 +109,22 @@ def warm_buckets(buckets=None, apps=("phold", "bulk"), log=None):
             state, params, app = _canonical_world(app_name, int(hb))
             real = int(state.hosts.num_hosts)
             state, params = pad_world_to_bucket(state, params)
-            # Warm every compiled flavor: megakernel AND persistent are
-            # ShapeKey statics (a fused world, its persistent-window
-            # variant and the reference oracle all trace different
-            # graphs), and benchdiff --kernels compares expect each to
-            # be hot.  persistent=True without megakernel never
-            # compiles (persistent_enabled requires the fused gate), so
-            # three flavors cover the space.
-            for mk, ps in ((True, True), (True, False), (False, False)):
-                pmk = params.replace(megakernel=mk, persistent=ps)
-                t0 = time.perf_counter()
-                lowered = engine.run_until.lower(
-                    state, pmk, app, simtime.SIMTIME_ONE_SECOND)
-                t1 = time.perf_counter()
-                lowered.compile()
-                t2 = time.perf_counter()
-                rec = {"app": app_name, "bucket_hosts": int(hb),
-                       "real_hosts": real, "megakernel": bool(mk),
-                       "persistent": bool(ps),
-                       "lower_s": round(t1 - t0, 3),
-                       "compile_s": round(t2 - t1, 3)}
-                records.append(rec)
-                if log is not None:
-                    log(rec)
+            # Warm the flavor that runs: the world's own megakernel/
+            # persistent statics (both ShapeKey fields), which default to
+            # the reference graph, the one path every backend compiles.
+            t0 = time.perf_counter()
+            lowered = engine.run_until.lower(
+                state, params, app, simtime.SIMTIME_ONE_SECOND)
+            t1 = time.perf_counter()
+            lowered.compile()
+            t2 = time.perf_counter()
+            rec = {"app": app_name, "bucket_hosts": int(hb),
+                   "real_hosts": real,
+                   "megakernel": bool(params.megakernel),
+                   "persistent": bool(params.persistent),
+                   "lower_s": round(t1 - t0, 3),
+                   "compile_s": round(t2 - t1, 3)}
+            records.append(rec)
+            if log is not None:
+                log(rec)
     return records

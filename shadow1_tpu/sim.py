@@ -487,14 +487,16 @@ def run(state, params, app, until=None, profiler=None, devices=None,
     if bucket:
         from . import shapes
         state, params = shapes.pad_world_to_bucket(state, params)
-    t = params.stop_time if until is None else until
+    # A Python int either way: a weakly typed scalar and the i64
+    # params.stop_time array would key two compiles of run_until.
+    t = int(params.stop_time if until is None else until)
     if checkpoint_every:
         if not checkpoint_dir:
             raise ValueError(
                 "sim.run: checkpoint_every requires checkpoint_dir "
                 "(where ckpt/ and windows.jsonl land)")
         return _run_checkpointed(
-            state, params, app, int(t), profiler=profiler,
+            state, params, app, t, profiler=profiler,
             devices=devices, bucket=bucket, scope=scope, lineage=lineage,
             digest=digest, every_ns=int(checkpoint_every),
             ckdir=checkpoint_dir, world=checkpoint_world,
@@ -545,14 +547,14 @@ def run(state, params, app, until=None, profiler=None, devices=None,
         state = _install_lineage(state, n)
         state = _install_digest(state, n)
         if profiler is None:
-            return parallel.mesh_run_chunked(state, params, app, int(t),
+            return parallel.mesh_run_chunked(state, params, app, t,
                                              mesh=mesh)
         from . import trace
         trace.install(profiler)
         try:
             if getattr(profiler, "counters", True):
                 state = trace.ensure_counters(state)
-            state = parallel.mesh_run_chunked(state, params, app, int(t),
+            state = parallel.mesh_run_chunked(state, params, app, t,
                                               mesh=mesh)
             trace.fetch_counters(state, profiler)
             return state
@@ -568,7 +570,7 @@ def run(state, params, app, until=None, profiler=None, devices=None,
     try:
         if getattr(profiler, "counters", True):
             state = trace.ensure_counters(state)
-        state = engine.run_chunked(state, params, app, int(t))
+        state = engine.run_chunked(state, params, app, t)
         trace.fetch_counters(state, profiler)
         return state
     finally:

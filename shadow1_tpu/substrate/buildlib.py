@@ -15,13 +15,12 @@ import pathlib
 import subprocess
 
 _NATIVE = pathlib.Path(__file__).resolve().parents[2] / "native"
-_CACHE = pathlib.Path(
-    os.environ.get("SHADOW1_TPU_CACHE",
-                   os.path.join(os.path.expanduser("~"), ".cache",
-                                "shadow1_tpu_xla"))).parent / "shadow1_native"
+# Fixed path inside the checkout (gitignored); names carry the source
+# hash, so what loads is always built from the committed sources.
+_CACHE = _NATIVE / "build"
 
 
-def _build(src: pathlib.Path, out_name: str, compiler: str,
+def build_lib(src: pathlib.Path, out_name: str, compiler: str,
            extra: list[str]) -> str:
     _CACHE.mkdir(parents=True, exist_ok=True)
     tag = hashlib.sha256(src.read_bytes()).hexdigest()[:16]
@@ -38,12 +37,12 @@ def _build(src: pathlib.Path, out_name: str, compiler: str,
 
 
 def shim_path() -> str:
-    return _build(_NATIVE / "shim" / "shadow1_shim.c", "shadow1_shim",
+    return build_lib(_NATIVE / "shim" / "shadow1_shim.c", "shadow1_shim",
                   "cc", ["-ldl", "-lpthread"])
 
 
 def sequencer_path() -> str:
-    return _build(_NATIVE / "sequencer.cc", "sequencer", "c++", [])
+    return build_lib(_NATIVE / "sequencer.cc", "sequencer", "c++", [])
 
 
 def build_binary(src: pathlib.Path, name: str) -> str:

@@ -39,6 +39,19 @@ def test_phold_runs_and_conserves_messages():
     assert int(out.now) == 500 * MS
 
 
+def test_run_until_one_compile_for_both_stop_forms():
+    """sim.run with until=None (params.stop_time, an i64 array) and with
+    an explicit int must key the same run_until compile."""
+    from shadow1_tpu.core import engine
+    state, params, app = sim.build_phold(
+        num_hosts=8, latency_ns=10 * MS, stop_time=500 * MS, seed=3)
+    before = engine.run_until._cache_size()
+    a = sim.run(state, params, app)
+    b = sim.run(state, params, app, until=500 * MS)
+    assert engine.run_until._cache_size() - before <= 1
+    assert _counters(a) == _counters(b)
+
+
 @pytest.mark.tier0
 def test_phold_deterministic_across_window_batching():
     state, params, app = sim.build_phold(

@@ -230,8 +230,11 @@ def test_checkpoint_stacked_round_trip(tmp_path):
 
 def test_checkpoint_load_world_slice_bitwise(tmp_path):
     # load(world=K) slices member K solo, bitwise ensemble.world's view
-    # (the anchor `replay --world K` restores).
-    estate, eparams, app = ensemble.stack([_phold(1), _phold(2)])
+    # (the anchor `replay --world K` restores).  The members ask for
+    # the fused path, which stack() must force off.
+    estate, eparams, app = ensemble.stack(
+        [(s, p.replace(megakernel=True), a)
+         for s, p, a in (_phold(1), _phold(2))])
     estate = ensemble.run_until(estate, eparams, app, SEC)
     path = str(tmp_path / "w.npz")
     checkpoint.save(path, estate, eparams)

@@ -136,17 +136,21 @@ class TestRateSpec:
         # do not carry; traced worlds take the reference graph
         # (docs/megakernel.md, follow-ups).
         state, params, app = _phold()
+        params = params.replace(megakernel=True)
         assert megakernel.enabled(state, params, app)
         traced = trace.ensure_lineage(state, rate=1.0)
         assert not megakernel.enabled(traced, params, app)
 
 
 class TestStructuralCost:
-    def test_lineage_absent_graph_identical_and_zero_kernel_delta(self):
+    @pytest.mark.parametrize("fused", [False, True])
+    def test_lineage_absent_graph_identical_and_zero_kernel_delta(
+            self, fused):
         # lineage=None is a trace-time static: attach-then-detach
         # lowers to byte-identical HLO, so the kernelcount delta is
-        # exactly 0.
+        # exactly 0 -- on the reference graph and the fused one.
         state, params, app = _lossy_bulk()
+        params = params.replace(megakernel=fused, persistent=fused)
         txt = engine.run_until.lower(state, params, app, SEC).as_text()
         rt = trace.ensure_lineage(state).replace(lineage=None)
         txt_rt = engine.run_until.lower(rt, params, app, SEC).as_text()

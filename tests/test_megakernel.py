@@ -66,6 +66,14 @@ def _assert_bitwise(fused, ref, label):
             f"{label}: leaf {jax.tree_util.keystr(path)} diverged")
 
 
+def _fused(world):
+    """The world with the fused statics set explicitly (the defaults are
+    the reference graph): megakernel on, persistent window kernel on.
+    Off the TPU the kernels run in Pallas interpret mode."""
+    state, params, app = world
+    return state, params.replace(megakernel=True, persistent=True), app
+
+
 def _phold(**kw):
     kw.setdefault("num_hosts", 16)
     kw.setdefault("msgs_per_host", 2)
@@ -73,7 +81,7 @@ def _phold(**kw):
     kw.setdefault("stop_time", 2 * SEC)
     kw.setdefault("pool_capacity", 16 * 8)
     kw.setdefault("seed", 7)
-    return sim.build_phold(**kw)
+    return _fused(sim.build_phold(**kw))
 
 
 class TestPholdNeutrality:
@@ -81,7 +89,6 @@ class TestPholdNeutrality:
     @pytest.mark.parametrize("rx_batch", [1, 2])
     def test_run_until_bitwise_identical(self, rx_batch):
         state, params, app = _phold(rx_batch=rx_batch)
-        assert params.megakernel, "megakernel should default on"
         fused = engine.run_until(state, params, app, SEC)
         ref = engine.run_until(state, params.replace(megakernel=False),
                                app, SEC)
@@ -132,9 +139,9 @@ class TestTcpNeutrality:
 
     @pytest.mark.parametrize("reliability", [1.0, 0.97])
     def test_bulk_bitwise_identical(self, reliability):
-        state, params, app = sim.build_bulk(
+        state, params, app = _fused(sim.build_bulk(
             num_hosts=4, bytes_per_client=30_000,
-            reliability=reliability, stop_time=4 * SEC, seed=11)
+            reliability=reliability, stop_time=4 * SEC, seed=11))
         fused = engine.run_until(state, params, app, 3 * SEC)
         ref = engine.run_until(state, params.replace(megakernel=False),
                                app, 3 * SEC)
@@ -155,7 +162,6 @@ class TestPersistentNeutrality:
     @pytest.mark.parametrize("rx_batch", [1, 2])
     def test_run_until_bitwise_identical(self, rx_batch):
         state, params, app = _phold(rx_batch=rx_batch)
-        assert params.persistent, "persistent should default on"
         on = engine.run_until(state, params, app, SEC)
         off = engine.run_until(state, params.replace(persistent=False),
                                app, SEC)
@@ -177,9 +183,9 @@ class TestPersistentNeutrality:
         # Drops arm RTO timers and retransmissions inside the window
         # loop; the congestion window math runs in-kernel -- cubic's
         # f32 cbrt is the sharpest in-kernel-contract probe in tree.
-        state, params, app = sim.build_bulk(
+        state, params, app = _fused(sim.build_bulk(
             num_hosts=4, bytes_per_client=30_000,
-            reliability=0.97, stop_time=4 * SEC, seed=11)
+            reliability=0.97, stop_time=4 * SEC, seed=11))
         params = params.replace(cong=cong)
         on = engine.run_until(state, params, app, 3 * SEC)
         off = engine.run_until(state, params.replace(persistent=False),

@@ -304,13 +304,22 @@ class TestTgenMesh:
 
 
 class TestSimRunDevices:
-    def test_sim_run_devices_matches_single_device_chunked(self):
+    @pytest.mark.parametrize("committed", [False, True],
+                             ids=["as_built", "committed_to_device0"])
+    def test_sim_run_devices_matches_single_device_chunked(self,
+                                                           committed):
         # sim.run(devices=N) is the library front door to the mesh path;
         # chunk boundaries mirror engine.run_chunked's, so the result is
-        # bitwise-comparable to the single-device chunked run.
+        # bitwise-comparable to the single-device chunked run.  On an
+        # accelerator build_on_host leaves the world committed to
+        # jax.devices()[0]; the mesh must lay it out itself (on the v5e
+        # it raised "incompatible devices" before, PR 21).
         kw = dict(num_hosts=16, msgs_per_host=2, latency_ns=10 * MS,
                   stop_time=200 * MS, pool_capacity=1 << 10, seed=9)
         state, params, app = sim.build_phold(**kw)
+        if committed:
+            state, params = jax.device_put((state, params),
+                                           jax.devices()[0])
         ref = engine.run_chunked(state, params, app, 200 * MS)
         out = sim.run(state, params, app, until=200 * MS, devices=8)
         _assert_trees_equal(jax.device_get(ref), jax.device_get(out))

@@ -69,10 +69,14 @@ def _poison_srtt(state, value=NAN_BITS):
 
 
 class TestStructuralCost:
-    def test_sentinel_absent_graph_identical_and_zero_kernel_delta(self):
+    @pytest.mark.parametrize("fused", [False, True])
+    def test_sentinel_absent_graph_identical_and_zero_kernel_delta(
+            self, fused):
         # sentinel=None is a trace-time static: attach-then-detach
-        # lowers to byte-identical HLO, so the kernelcount delta is 0.
+        # lowers to byte-identical HLO, so the kernelcount delta is 0
+        # -- on the reference graph and the fused one.
         state, params, app = _lossy_bulk()
+        params = params.replace(megakernel=fused, persistent=fused)
         txt = engine.run_until.lower(state, params, app, SEC).as_text()
         rt = trace.ensure_sentinel(state).replace(sentinel=None)
         txt_rt = engine.run_until.lower(rt, params, app, SEC).as_text()

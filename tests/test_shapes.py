@@ -105,22 +105,16 @@ class TestShapeKeyLadder:
         key = dataclasses.replace(shape_key(s, p), hosts=2_000_000)
         assert shapes.bucket_for(key).hosts == 2_000_000
 
-    def test_bucketing_never_enters_the_known_bad_region(self):
-        # A slab-128 world below 10k hosts must NOT round up into the
-        # known-bad (hosts, slab) region (core/state.py
-        # warn_known_bad_pool): the bucket stays exact, with a warning.
+    def test_large_slab_worlds_bucket_like_any_other(self):
+        # Hosts round up the ladder whatever the slab, and the slab
+        # itself never rounds (trajectory-visible).
         s, p, _ = sim.build_phold(20, stop_time=SEC, pool_capacity=20 * 8)
         key = dataclasses.replace(shape_key(s, p),
                                   hosts=9_000, pool_slab=128)
-        with pytest.warns(UserWarning, match="known-bad"):
-            b = shapes.bucket_for(key)
-        assert b.hosts == 9_000
-        # Already inside the region: bucketing proceeds normally (the
-        # world was warned at build time; rounding adds no new hazard).
+        b = shapes.bucket_for(key)
+        assert (b.hosts, b.pool_slab) == (16_384, 128)
         key_in = dataclasses.replace(key, hosts=20_000)
-        b_in = shapes.bucket_for(key_in)
-        assert b_in.hosts == 65_536
-        # A small-slab world of the same size buckets normally too.
+        assert shapes.bucket_for(key_in).hosts == 65_536
         key_ok = dataclasses.replace(key, pool_slab=8, inbox_slab=8)
         assert shapes.bucket_for(key_ok).hosts == 16_384
 

@@ -48,12 +48,13 @@ def _bulk():
     return sim.build_bulk(**BULK_KW)
 
 
-def _ckrun(ckdir, supervise_opt=None, stop=2 * SEC):
+def _ckrun(ckdir, supervise_opt=None, stop=2 * SEC, megakernel=False):
     # The bulk world is all done by ~1.5s, so a 0.5s cadence leaves
     # several MID-ACTIVITY checkpoints -- poison anchored there is
     # guaranteed to be followed by executed (= sentinel-checked)
     # windows, which a cadence past the activity tail would not.
     state, params, app = _bulk()
+    params = params.replace(megakernel=megakernel)
     out = sim.run(state, params, app, until=stop,
                   checkpoint_every=SEC // 2, checkpoint_dir=str(ckdir),
                   checkpoint_world=("bulk", BULK_KW),
@@ -61,9 +62,11 @@ def _ckrun(ckdir, supervise_opt=None, stop=2 * SEC):
     return out, params, app
 
 
-def _poison_mid(d):
+def _poison_mid(d, megakernel=False):
     """NaN-poison the srtt leaf of the run's second checkpoint, drop
-    every later one, and return (path, manifest, built-world)."""
+    every later one, and return (path, manifest, built-world).  The
+    rebuilt params carry the run's `megakernel` static (a ShapeKey
+    field the checkpoint is stamped with)."""
     idx_path = os.path.join(d, "ckpt", "index.json")
     with open(idx_path) as f:
         idx = json.load(f)
@@ -77,6 +80,7 @@ def _poison_mid(d):
 
     info = replay.load_run(d)
     built = replay.rebuild_world(info, d, want_mesh=False)
+    built["params"] = built["params"].replace(megakernel=megakernel)
     path = os.path.join(d, "ckpt", entries[1]["file"])
     man = checkpoint.read_manifest(path)
     state, params = checkpoint.load(path, built["state"],
@@ -221,9 +225,11 @@ class TestSupervisedRun:
         # sentinel in the first window, skip plain retry (deterministic
         # class), exhaust the bitwise-neutral rungs, and surrender rc 1
         # with a complete crash report.
+        # The run asks for the fused path, so the megakernel_off rung
+        # has something to turn off.
         d = str(tmp_path)
-        _ckrun(d, supervise_opt=True)
-        path, man, built = _poison_mid(d)
+        _ckrun(d, supervise_opt=True, megakernel=True)
+        path, man, built = _poison_mid(d, megakernel=True)
         state, params = checkpoint.load(path, built["state"],
                                         built["params"])
         sup = supervise.Supervisor(d, built["app"], quiet=True,
@@ -253,7 +259,7 @@ class TestSupervisedRun:
         # therefore every checkpoint's static stamp) keep the canonical
         # megakernel flag, so replay templates stay valid.
         state, params, app = _bulk()
-        assert params.megakernel is True
+        params = params.replace(megakernel=True)
         seen = []
 
         sup = supervise.Supervisor(str(tmp_path), app, quiet=True)

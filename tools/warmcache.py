@@ -4,7 +4,8 @@ Thin front end over shadow1_tpu.shapes.warm_buckets (the same entry
 `shadow1-tpu warm` uses): builds one canonical world per (app flavor,
 host bucket), pads it into its bucket, and AOT lowers + compiles
 engine.run_until so the executable lands in the persistent compilation
-cache (SHADOW1_TPU_CACHE, default ~/.cache/shadow1_tpu_xla).  Later
+cache (JAX_COMPILATION_CACHE_DIR where set, else `.jax_cache/` in the
+checkout).  Later
 processes tracing the same graph skip the backend compile entirely --
 `profile.compiles` / `compile_ms` (trace.py, gated by tools/benchdiff.py)
 make the win measurable.  See docs/shapes.md.
