@@ -38,6 +38,10 @@ if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
                        _os.path.join(_ROOT, ".jax_cache"))
 _jax.config.update("jax_persistent_cache_min_compile_time_secs", 2)
 
+# The compile record (trace.compile_spans) listens from import on, so the
+# first compile of the process is in it.
+from . import trace as _trace  # noqa: E402,F401
+
 
 def build_on_host(fn, *args, **kwargs):
     """Run a state-construction function with the local CPU as the default

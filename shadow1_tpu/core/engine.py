@@ -56,6 +56,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..trace import phase
 from . import emit, nic, rng, simtime
 # Reliability-dropped packets are never materialized in the pool (they are
 # counted in HostTable.pkts_dropped_inet instead), so PDS_INET_DROPPED is
@@ -99,10 +100,12 @@ def _mesh_reduce(x, lax_op, jnp_op):
     """Cross-shard min/max.  The TPU lowers a 64-bit all-reduce only as
     a sum, so 64-bit operands (simulated time, i64 counters) gather and
     reduce locally instead -- exact, since min/max do not depend on
-    order; 32-bit operands keep the one-collective form."""
-    if jnp.dtype(x.dtype).itemsize == 8:
-        return jnp_op(jax.lax.all_gather(x, MESH_AXIS), axis=0)
-    return lax_op(x, MESH_AXIS)
+    order; 32-bit operands keep the one-collective form.  Both scope as
+    the `mesh_min` phase, nested in the phase that asks."""
+    with phase("mesh_min"):
+        if jnp.dtype(x.dtype).itemsize == 8:
+            return jnp_op(jax.lax.all_gather(x, MESH_AXIS), axis=0)
+        return lax_op(x, MESH_AXIS)
 
 
 def mesh_min(x):
@@ -2247,7 +2250,8 @@ def _microstep_core(state: SimState, params, app, t_h, window_end,
     from ..transport import tcp as tcp_mod
 
     if ctx is None:
-        ctx = _window_ctx(state, params)
+        with phase("bounds"):
+            ctx = _window_ctx(state, params)
     bw_up, bw_dn, alive = ctx
 
     h = state.hosts.num_hosts
@@ -2256,15 +2260,6 @@ def _microstep_core(state: SimState, params, app, t_h, window_end,
             "this world's inbox was built narrow (uses_tcp=False in "
             "make_sim_state) but the app uses TCP; TCP segments need the "
             "TS/SACK inbox columns")
-    active = t_h < window_end
-    tick_t = jnp.where(active, t_h, window_end)
-
-    # Active hosts' resume flags are re-armed by this tick's phases;
-    # inactive hosts keep theirs (token-accrual wake-ups must survive).
-    state = state.replace(
-        hosts=state.hosts.replace(t_resume=jnp.where(
-            active, jnp.asarray(INV, I64), state.hosts.t_resume)))
-
     if _uses_tcp(app):
         # Extra reply lanes for rx_batch delivery rounds beyond the first
         # (each round's TCP reply needs its own emission slot).
@@ -2278,13 +2273,24 @@ def _microstep_core(state: SimState, params, app, t_h, window_end,
     # The staging block matches the world's outbox width: TCP-free worlds
     # stage 18-column rows (no TS/TSE/SACK), shrinking both emit.put's
     # row stack and the staging merge (PERF.md round 7).
-    em = emit.empty(h, n_lanes, cols=state.pool.blk.shape[1])
+    # Each phase below runs under its trace.PHASES scope; the tick's
+    # set-up rides with `rx`.
+    with phase("rx"):
+        active = t_h < window_end
+        tick_t = jnp.where(active, t_h, window_end)
 
-    # Phase A: arrivals through the destination slab (router queue, NIC rx
-    # tokens + CoDel, transport delivery).
-    state, em, delivered_n, t_post = _rx_phase(state, params, em, tick_t,
-                                               active, app, window_end,
-                                               bw_dn=bw_dn, alive=alive)
+        # Active hosts' resume flags are re-armed by this tick's phases;
+        # inactive hosts keep theirs (token-accrual wake-ups must survive).
+        state = state.replace(
+            hosts=state.hosts.replace(t_resume=jnp.where(
+                active, jnp.asarray(INV, I64), state.hosts.t_resume)))
+        em = emit.empty(h, n_lanes, cols=state.pool.blk.shape[1])
+
+        # Phase A: arrivals through the destination slab (router queue,
+        # NIC rx tokens + CoDel, transport delivery).
+        state, em, delivered_n, t_post = _rx_phase(
+            state, params, em, tick_t, active, app, window_end,
+            bw_dn=bw_dn, alive=alive)
 
     # Phases B-D run at the POST-BATCH per-host instant: when rx_batch
     # rounds consumed arrivals slightly after tick_t, every downstream
@@ -2293,44 +2299,52 @@ def _microstep_core(state: SimState, params, app, t_h, window_end,
     # timer/app event was due inside (tick_t, t_post], so ordering is
     # preserved.  For rx_batch=1 apps t_post == tick_t exactly.
     if _uses_tcp(app):
-        state, em = tcp_mod.run_timers(state, params, em, t_post, active)
+        with phase("tcp_timers"):
+            state, em = tcp_mod.run_timers(state, params, em, t_post,
+                                           active)
 
     # Phase C: application tick.
     if app is not None:
-        if getattr(app, "wants_window_end", False):
-            # The window bound lets the app pre-emit future sends that
-            # provably precede its next possible arrival (send batching).
-            state, em = app.on_tick(state, params, em, t_post, active,
-                                    window_end=window_end)
-        else:
-            state, em = app.on_tick(state, params, em, t_post, active)
+        with phase("app"):
+            if getattr(app, "wants_window_end", False):
+                # The window bound lets the app pre-emit future sends that
+                # provably precede its next possible arrival (send
+                # batching).
+                state, em = app.on_tick(state, params, em, t_post, active,
+                                        window_end=window_end)
+            else:
+                state, em = app.on_tick(state, params, em, t_post, active)
 
     # Phase D: TCP transmission, merge staged emissions into the outbox
     # (direct-admit or park) or own inbox (loopback), then drain parked
     # packets through the tx bucket.
     if _uses_tcp(app):
-        state, em = tcp_mod.transmit(state, params, em, t_post, active)
-    state, placed = _stage_emissions(state, params, em, t_post, active,
-                                     app, bw_up=bw_up)
-    state = _tx_drain(state, params, t_post, active, bw_up=bw_up)
+        with phase("tcp_tx"):
+            state, em = tcp_mod.transmit(state, params, em, t_post, active)
+    with phase("stage"):
+        state, placed = _stage_emissions(state, params, em, t_post, active,
+                                         app, bw_up=bw_up)
+    with phase("tx"):
+        state = _tx_drain(state, params, t_post, active, bw_up=bw_up)
 
     # Virtual CPU accounting (reference cpu_updateTime + cpu_addDelay,
     # cpu.c:77-108): every delivered packet and staged emission costs
     # cpu_ns_per_event.  Costs accumulate exactly; precision rounding
     # happens where the backlog is consulted (_cpu_clamp), so per-step
     # increments smaller than the precision are never lost.
-    cpu_on = params.cpu_ns_per_event > 0
-    events = delivered_n.astype(I64) + \
-        jnp.sum(em.valid, axis=1).astype(I64)
-    cost = params.cpu_ns_per_event * events
-    avail = jnp.maximum(state.hosts.cpu_avail, tick_t)
-    new_avail = jnp.where(cpu_on & active, avail + cost,
-                          state.hosts.cpu_avail)
-    state = state.replace(
-        hosts=state.hosts.replace(cpu_avail=new_avail),
-        n_steps=state.n_steps + 1,
-        n_events=state.n_events + jnp.sum(events),
-    )
+    with phase("cpu"):
+        cpu_on = params.cpu_ns_per_event > 0
+        events = delivered_n.astype(I64) + \
+            jnp.sum(em.valid, axis=1).astype(I64)
+        cost = params.cpu_ns_per_event * events
+        avail = jnp.maximum(state.hosts.cpu_avail, tick_t)
+        new_avail = jnp.where(cpu_on & active, avail + cost,
+                              state.hosts.cpu_avail)
+        state = state.replace(
+            hosts=state.hosts.replace(cpu_avail=new_avail),
+            n_steps=state.n_steps + 1,
+            n_events=state.n_events + jnp.sum(events),
+        )
     return state
 
 
@@ -2363,13 +2377,16 @@ def _window_body_ref(state: SimState, params, app, t_target):
     the same one the main-graph window body traces, which is what the
     persistent path's bitwise contract rests on (docs/megakernel.md,
     "Persistent window kernel")."""
-    st = _exchange(state, params, fused=False)
-    t_h, gmin = _scan_all(st, params, app)
-    ws = jnp.maximum(st.now, gmin)
-    we = jnp.minimum(ws + params.min_latency_ns, t_target)
-    if st.nm is not None:
-        st = st.replace(nm=netem_apply.advance(st.nm, we))
-    ctx = _window_ctx(st, params)
+    with phase("exchange"):
+        st = _exchange(state, params, fused=False)
+    with phase("scan"):
+        t_h, gmin = _scan_all(st, params, app)
+    with phase("bounds"):
+        ws = jnp.maximum(st.now, gmin)
+        we = jnp.minimum(ws + params.min_latency_ns, t_target)
+        if st.nm is not None:
+            st = st.replace(nm=netem_apply.advance(st.nm, we))
+        ctx = _window_ctx(st, params)
 
     def icond(icarry):
         _s, _th, g = icarry
@@ -2378,11 +2395,13 @@ def _window_body_ref(state: SimState, params, app, t_target):
     def ibody(icarry):
         s, th, _ = icarry
         s = _microstep_core(s, params, app, th, we, ctx=ctx)
-        th2, g2 = _scan_all(s, params, app)
+        with phase("scan"):
+            th2, g2 = _scan_all(s, params, app)
         return s, th2, g2
 
     st, t_h, gmin = jax.lax.while_loop(icond, ibody, (st, t_h, gmin))
-    st = st.replace(now=we, n_windows=st.n_windows + 1)
+    with phase("close"):
+        st = st.replace(now=we, n_windows=st.n_windows + 1)
     return st, t_h, gmin, ws, we
 
 
@@ -2428,16 +2447,20 @@ def run_until_impl(state: SimState, params, app, t_target):
     fused = mk.enabled(state, params, app)
     persistent = mk.persistent_enabled(state, params, app)
 
+    # Every op below runs under a trace.PHASES scope (the window records
+    # taken at window open belong to `close`, with the rest of them).
     def scan(s):
-        t_h, gmin = _scan_all(s, params, app)
-        if mesh:
-            gmin = mesh_min(gmin)
+        with phase("scan"):
+            t_h, gmin = _scan_all(s, params, app)
+            if mesh:
+                gmin = mesh_min(gmin)
         return t_h, gmin
 
     def outbox_pending(s):
-        g = _outbox_pending(s)
-        if mesh:
-            g = mesh_min(g)
+        with phase("scan"):
+            g = _outbox_pending(s)
+            if mesh:
+                g = mesh_min(g)
         return g
 
     def window_cond(carry):
@@ -2447,12 +2470,13 @@ def run_until_impl(state: SimState, params, app, t_target):
 
     def window_body(carry):
         st, _, _, _ = carry
-        if st.fr is not None:
-            st, fr_snap = _fr_snapshot(st)
-        if st.sentinel is not None:
-            # Conservation ledger at window open, before the exchange
-            # (which thins acks and drops data mid-identity).
-            sn_snap = _sentinel_counters(st)
+        with phase("close"):
+            if st.fr is not None:
+                st, fr_snap = _fr_snapshot(st)
+            if st.sentinel is not None:
+                # Conservation ledger at window open, before the exchange
+                # (which thins acks and drops data mid-identity).
+                sn_snap = _sentinel_counters(st)
         if persistent:
             # K_WINDOW: the whole window -- exchange, scan, bounds,
             # netem advance, and the micro-step while loop -- as ONE
@@ -2470,33 +2494,37 @@ def run_until_impl(state: SimState, params, app, t_target):
             core = st.replace(scope=None, sentinel=None, dg=None)
             core, t_h, gmin, ws, we = mk.window_fused(
                 core, params, app, t_target)
-            st = core.replace(scope=scope_b, sentinel=sent_b, dg=dg_b)
-            if st.fr is not None:
-                st = _fr_record(st, fr_snap, ws, we)
-            if st.scope is not None:
-                st = _scope_sample(st, _window_ctx(st, params), we)
-            if st.sentinel is not None:
-                st = _sentinel_check(st, sn_snap, ws, we)
-            if st.dg is not None:
-                st = _digest_record(st, we)
+            with phase("close"):
+                st = core.replace(scope=scope_b, sentinel=sent_b, dg=dg_b)
+                if st.fr is not None:
+                    st = _fr_record(st, fr_snap, ws, we)
+                if st.scope is not None:
+                    st = _scope_sample(st, _window_ctx(st, params), we)
+                if st.sentinel is not None:
+                    st = _sentinel_check(st, sn_snap, ws, we)
+                if st.dg is not None:
+                    st = _digest_record(st, we)
             return st, t_h, gmin, outbox_pending(st)
         # Boundary exchange first: everything in flight becomes visible
         # in the destination slabs before the window's scan.
-        st = _exchange(st, params, fused=fused and not mesh)
+        with phase("exchange"):
+            st = _exchange(st, params, fused=fused and not mesh)
         t_h, gmin = scan(st)
-        ws = jnp.maximum(st.now, gmin)
-        we = jnp.minimum(ws + params.min_latency_ns, t_target)
-        if st.nm is not None:
-            # Apply every fault event inside this window before any of
-            # its ticks: an event takes effect at the start of the
-            # conservative window containing its timestamp (install()
-            # already shrank the lookahead for sub-1.0 latency scales).
-            st = st.replace(nm=netem_apply.advance(st.nm, we))
+        with phase("bounds"):
+            ws = jnp.maximum(st.now, gmin)
+            we = jnp.minimum(ws + params.min_latency_ns, t_target)
+            if st.nm is not None:
+                # Apply every fault event inside this window before any
+                # of its ticks: an event takes effect at the start of the
+                # conservative window containing its timestamp (install()
+                # already shrank the lookahead for sub-1.0 latency
+                # scales).
+                st = st.replace(nm=netem_apply.advance(st.nm, we))
 
-        # Hoist the window-invariant micro-step inputs here: the inner
-        # while body closes over them, so XLA computes them once per
-        # window instead of once per micro-step.
-        ctx = _window_ctx(st, params)
+            # Hoist the window-invariant micro-step inputs here: the
+            # inner while body closes over them, so XLA computes them
+            # once per window instead of once per micro-step.
+            ctx = _window_ctx(st, params)
 
         def icond(icarry):
             _s, _th, g = icarry
@@ -2518,32 +2546,35 @@ def run_until_impl(state: SimState, params, app, t_target):
             return s, th2, g2
 
         st, t_h, gmin = jax.lax.while_loop(icond, ibody, (st, t_h, gmin))
-        st = st.replace(now=we, n_windows=st.n_windows + 1)
-        if st.fr is not None:
-            st = _fr_record(st, fr_snap, ws, we)
-        if st.scope is not None:
-            # Sample at window close: the cadence check and cursors are
-            # replicated, so every shard takes the same branch.
-            st = _scope_sample(st, ctx, we)
-        if st.sentinel is not None:
-            st = _sentinel_check(st, sn_snap, ws, we)
-        if st.dg is not None:
-            # Digest at window close: the cadence predicate is a
-            # function of the replicated window counter, so every shard
-            # takes the same branch around the gather inside.
-            st = _digest_record(st, we)
+        with phase("close"):
+            st = st.replace(now=we, n_windows=st.n_windows + 1)
+            if st.fr is not None:
+                st = _fr_record(st, fr_snap, ws, we)
+            if st.scope is not None:
+                # Sample at window close: the cadence check and cursors
+                # are replicated, so every shard takes the same branch.
+                st = _scope_sample(st, ctx, we)
+            if st.sentinel is not None:
+                st = _sentinel_check(st, sn_snap, ws, we)
+            if st.dg is not None:
+                # Digest at window close: the cadence predicate is a
+                # function of the replicated window counter, so every
+                # shard takes the same branch around the gather inside.
+                st = _digest_record(st, we)
         return st, t_h, gmin, outbox_pending(st)
 
     t_h0, gmin0 = scan(state)
     state, _, _, _ = jax.lax.while_loop(
         window_cond, window_body,
         (state, t_h0, gmin0, outbox_pending(state)))
-    if state.nm is not None:
-        # Catch up through idle spans the window loop skipped, so the
-        # cursor (and every counter derived from it) is canonical at
-        # t_target regardless of how the run was chunked.
-        state = state.replace(nm=netem_apply.advance(state.nm, t_target))
-    return state.replace(now=t_target)
+    with phase("close"):
+        if state.nm is not None:
+            # Catch up through idle spans the window loop skipped, so the
+            # cursor (and every counter derived from it) is canonical at
+            # t_target regardless of how the run was chunked.
+            state = state.replace(nm=netem_apply.advance(state.nm,
+                                                         t_target))
+        return state.replace(now=t_target)
 
 
 # One device launch covers this much simulated time: long enough to

@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from .. import trace
 from ..core import engine
 from .sharding import (HOST_AXIS, PARAM_SPECS, _leaf_name, make_mesh,
                        pad_world_to_mesh)
@@ -131,28 +132,34 @@ def _build(app, mesh, sspecs, pspecs):
         # bits, pmax would drop them).  now/n_steps/n_windows/exchanges
         # are uniform for free: every loop predicate is pmin/pmax'd, so
         # all shards run identical trip counts.
-        errs = jax.lax.all_gather(st.err, HOST_AXIS)
-        err = errs[0]
-        for i in range(1, n_shards):
-            err = err | errs[i]
-        st = st.replace(
-            err=err,
-            n_events=n_ev0 + jax.lax.psum(st.n_events - n_ev0, HOST_AXIS))
-        if killed0 is not None:
-            st = st.replace(nm=st.nm.replace(
-                killed=killed0
-                + jax.lax.psum(st.nm.killed - killed0, HOST_AXIS)))
-        if tr0 is not None:
-            st = st.replace(tr=st.tr.replace(
-                pkts_exchanged=tr0.pkts_exchanged + jax.lax.psum(
-                    st.tr.pkts_exchanged - tr0.pkts_exchanged, HOST_AXIS),
-                occ_max=engine.mesh_max(st.tr.occ_max)))
-        if ln0 is not None:
-            st = st.replace(lineage=st.lineage.replace(
-                n_assigned=ln0 + jax.lax.psum(
-                    st.lineage.n_assigned - ln0, HOST_AXIS)))
+        with trace.phase("close"):
+            errs = jax.lax.all_gather(st.err, HOST_AXIS)
+            err = errs[0]
+            for i in range(1, n_shards):
+                err = err | errs[i]
+            st = st.replace(
+                err=err,
+                n_events=n_ev0 + jax.lax.psum(st.n_events - n_ev0,
+                                              HOST_AXIS))
+            if killed0 is not None:
+                st = st.replace(nm=st.nm.replace(
+                    killed=killed0
+                    + jax.lax.psum(st.nm.killed - killed0, HOST_AXIS)))
+            if tr0 is not None:
+                st = st.replace(tr=st.tr.replace(
+                    pkts_exchanged=tr0.pkts_exchanged + jax.lax.psum(
+                        st.tr.pkts_exchanged - tr0.pkts_exchanged,
+                        HOST_AXIS),
+                    occ_max=engine.mesh_max(st.tr.occ_max)))
+            if ln0 is not None:
+                st = st.replace(lineage=st.lineage.replace(
+                    n_assigned=ln0 + jax.lax.psum(
+                        st.lineage.n_assigned - ln0, HOST_AXIS)))
         return st.replace(hoff=None)
 
+    # The compile record (trace.compile_spans) names this function's
+    # trace, lowering and compile after it.
+    body.__name__ = body.__qualname__ = "mesh_run_until"
     return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(sspecs, pspecs, P()),
         out_specs=sspecs, check_vma=False))
@@ -262,7 +269,6 @@ def mesh_run_chunked(state, params, app, t_target: int, mesh=None,
     When a profiler is active (trace.install), each launch records a
     `device_step` span exactly like the single-device launcher, so
     metrics.json phase tables are comparable across device counts."""
-    from .. import trace
     if mesh is None:
         mesh = make_mesh()
     t = int(state.now)

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 
 import shadow1_tpu as _pkg
 
+from . import trace
 from .apps import bulk as bulk_app
 from .apps import phold as phold_app
 from .core import engine, simtime
@@ -376,12 +377,18 @@ class WindowPipeline:
         self.settle()
 
 
+@trace.spanned("sim.run")
 def run(state, params, app, until=None, profiler=None, devices=None,
         bucket=False, scope=None, lineage=None, digest=None,
         checkpoint_every=None, checkpoint_dir=None, checkpoint_world=None,
         supervise=None, control=None, emit=None, resume=False,
         pipeline=True):
     """Run to `until` (default: params.stop_time).
+
+    The call is span `sim.run`; inside it, on the paths below that
+    launch directly, span `prepare` covers padding and the installs of
+    the scope, lineage and digest blocks, and span `dispatch` the jitted
+    launches (trace.py: both reach a `jax.profiler` trace).
 
     With `profiler` (a trace.Profiler), the run is profiled: the
     profiler is installed, device counters ride the state, and the run
@@ -486,7 +493,8 @@ def run(state, params, app, until=None, profiler=None, devices=None,
     h_real = int(state.hosts.num_hosts)
     if bucket:
         from . import shapes
-        state, params = shapes.pad_world_to_bucket(state, params)
+        with trace.current().span("prepare"):
+            state, params = shapes.pad_world_to_bucket(state, params)
     # A Python int either way: a weakly typed scalar and the i64
     # params.stop_time array would key two compiles of run_until.
     t = int(params.stop_time if until is None else until)
@@ -512,26 +520,19 @@ def run(state, params, app, until=None, profiler=None, devices=None,
             "checkpoint_dir (parking and resuming are "
             "checkpoint-anchored)")
 
-    def _install_scope(st, shards):
-        if scope is None or st.scope is not None:
-            return st
-        from . import trace
-        return trace.ensure_flowscope(st, shards=shards,
-                                      **trace.parse_scope_spec(scope))
+    def _install_blocks(st, shards):
+        if scope is not None and st.scope is None:
+            st = trace.ensure_flowscope(st, shards=shards,
+                                        **trace.parse_scope_spec(scope))
+        if lineage is not None and st.lineage is None:
+            st = trace.ensure_lineage(
+                st, rate=trace.parse_lineage_rate(lineage), shards=shards)
+        if digest is not None and digest is not False and st.dg is None:
+            st = trace.ensure_digests(
+                st, every=1 if digest is True else int(digest),
+                shards=shards)
+        return st
 
-    def _install_lineage(st, shards):
-        if lineage is None or st.lineage is not None:
-            return st
-        from . import trace
-        return trace.ensure_lineage(
-            st, rate=trace.parse_lineage_rate(lineage), shards=shards)
-
-    def _install_digest(st, shards):
-        if digest is None or digest is False or st.dg is not None:
-            return st
-        from . import trace
-        return trace.ensure_digests(
-            st, every=1 if digest is True else int(digest), shards=shards)
     if devices is not None and int(devices) > 1:
         import jax as _jax
 
@@ -541,36 +542,30 @@ def run(state, params, app, until=None, profiler=None, devices=None,
         if len(devs) < n:
             raise ValueError(f"sim.run: devices={n} but only {len(devs)} "
                              f"{_jax.default_backend()} device(s) visible")
-        mesh = parallel.make_mesh(devs[:n])
-        state, params = parallel.pad_world_to_mesh(state, params, n)
-        state = _install_scope(state, n)
-        state = _install_lineage(state, n)
-        state = _install_digest(state, n)
-        if profiler is None:
-            return parallel.mesh_run_chunked(state, params, app, t,
-                                             mesh=mesh)
-        from . import trace
-        trace.install(profiler)
-        try:
-            if getattr(profiler, "counters", True):
-                state = trace.ensure_counters(state)
-            state = parallel.mesh_run_chunked(state, params, app, t,
-                                              mesh=mesh)
-            trace.fetch_counters(state, profiler)
-            return state
-        finally:
-            trace.install(None)
-    state = _install_scope(state, 1)
-    state = _install_lineage(state, 1)
-    state = _install_digest(state, 1)
+        with trace.current().span("prepare"):
+            mesh = parallel.make_mesh(devs[:n])
+            state, params = parallel.pad_world_to_mesh(state, params, n)
+            state = _install_blocks(state, n)
+
+        def launch(st):
+            return parallel.mesh_run_chunked(st, params, app, t, mesh=mesh)
+    else:
+        with trace.current().span("prepare"):
+            state = _install_blocks(state, 1)
+
+        def launch(st):
+            if profiler is None:
+                return engine.run_until(st, params, app, t)
+            return engine.run_chunked(st, params, app, t)
     if profiler is None:
-        return engine.run_until(state, params, app, t)
-    from . import trace
+        with trace.current().span("dispatch"):
+            return launch(state)
     trace.install(profiler)
     try:
         if getattr(profiler, "counters", True):
             state = trace.ensure_counters(state)
-        state = engine.run_chunked(state, params, app, t)
+        with profiler.span("dispatch"):
+            state = launch(state)
         trace.fetch_counters(state, profiler)
         return state
     finally:

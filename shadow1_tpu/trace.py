@@ -3,11 +3,26 @@
 `observe.py` watches the *simulated* world (heartbeats, pcap, drops);
 this module watches the *simulator*.  A `Profiler` records host-side
 phase spans (device launches, tracker/log drains, substrate syncs,
-bridge RPCs), device->host transfer volume, and JIT compile events (via
-JAX's monitoring hook), while a device-side `TraceCounters` block
-(core/state.py) accumulates per-window aggregates -- packets exchanged,
-peak inbox-slab occupancy -- inside the compiled step so they cost one
-extra scalar fetch per drain, not per window.
+bridge RPCs), device->host transfer volume, and JIT compile events,
+while a device-side `TraceCounters` block (core/state.py) accumulates
+per-window aggregates -- packets exchanged, peak inbox-slab occupancy --
+inside the compiled step so they cost one extra scalar fetch per drain,
+not per window.
+
+Three names reach a `jax.profiler` trace whether or not a Profiler is
+installed:
+
+* every span (`Profiler.span`, and the no-op profiler's) is also a
+  `jax.profiler.TraceAnnotation`, so `sim.run`, `prepare`, `dispatch`,
+  `device_step`, the drains and `progress` land on the trace's host
+  plane, on the clock of the device ops;
+* the window loop's phases are `jax.named_scope`s (`phase`, `PHASES`):
+  every compiled op's `op_name` metadata carries the innermost phase
+  that encloses it, so device time splits by phase
+  (tools/phaseprof.py);
+* JAX's compile-phase spans (trace, lowering, backend compile or cache
+  load) are kept from import on in one bounded list,
+  `compile_spans()`, which also feeds the Profiler's `compile` block.
 
 Three artifacts per profiled run:
 
@@ -28,14 +43,87 @@ the same present-or-None pattern as the capture and log rings.
 
 from __future__ import annotations
 
+import collections
+import functools
 import json
 import time
 
-# JAX's backend-compile duration event (jax._src.dispatch
-# BACKEND_COMPILE_EVENT): fires once per XLA compile, i.e. on every
-# compile-cache miss.  Resolved lazily so a rename in a future JAX only
-# degrades compile attribution, never breaks the profiler.
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+import jax
+from jax._src import monitoring as _monitoring
+from jax.profiler import TraceAnnotation
+
+# ---------------------------------------------------------------------------
+# Window-loop phases: named scopes on every compiled op
+# ---------------------------------------------------------------------------
+
+# The one set of phase names the engine scopes its ops under (engine.py).
+# Window level: `exchange` (the boundary exchange, the mesh's all_to_all
+# body included), `scan` (the next-event scans and outbox-pending mins
+# that drive both loops), `bounds` (window start/end, netem advance, the
+# hoisted window ctx), `close` (window records: flight, scope, sentinel,
+# digest).  Micro-step (`_microstep_core`): `rx`, `tcp_timers`, `app`,
+# `tcp_tx`, `stage`, `tx`, `cpu`, then `scan` again.  `mesh_min` is every
+# cross-chip min or max reduction (engine._mesh_reduce), nested inside the
+# phase that calls it.  An op belongs to the innermost phase in its
+# `op_name`; renaming one renames what every trace reduction reads.
+PHASES = ("exchange", "scan", "bounds", "close",
+          "rx", "tcp_timers", "app", "tcp_tx", "stage", "tx", "cpu",
+          "mesh_min")
+
+
+def phase(name):
+    """`jax.named_scope` for one of PHASES: metadata only, the compiled
+    ops stay the same (tools/kernelcount.py counts are unchanged)."""
+    if name not in PHASES:
+        raise ValueError(f"unknown phase {name!r}; PHASES are {PHASES}")
+    return jax.named_scope(name)
+
+
+# ---------------------------------------------------------------------------
+# Compile record: JAX's compile-phase spans, kept from import on
+# ---------------------------------------------------------------------------
+
+# JAX announces each phase of a compile with the function's name
+# (jax._src.dispatch): the Python trace (`fun_name` "run_until"), the
+# lowering to MLIR and the backend compile, which is also a persistent
+# cache load ("jit(run_until)" in jax 0.9).
+_COMPILE_PREFIX = "/jax/core/compile/"
+BACKEND_COMPILE = _COMPILE_PREFIX + "backend_compile_duration"
+COMPILE_SPANS_MAX = 16384
+_compile_spans = collections.deque(maxlen=COMPILE_SPANS_MAX)
+
+
+def compile_spans():
+    """Every JAX compile-phase span of this process since shadow1_tpu was
+    imported (the newest COMPILE_SPANS_MAX), oldest first, verbatim:
+    (event, fun_name, start_s, end_s) on the `time.time()` clock.  The
+    counter an operator reads to see which function recompiled."""
+    return list(_compile_spans)
+
+
+def _on_time_span(event, start_s, end_s, **kw):
+    """Keep a compile-phase span in the record and hand it to the active
+    Profiler.  Runs on whichever thread compiles; both appends are single
+    calls, and readers copy before they loop."""
+    if event.startswith(_COMPILE_PREFIX):
+        span = (event, kw.get("fun_name", ""), start_s, end_s)
+        _compile_spans.append(span)
+        p = _active
+        if p.enabled:
+            p.compile_spans.append(span)
+
+
+# The one process-wide listener (JAX has no scoped one), registered when
+# the package is imported.
+_monitoring.register_event_time_span_listener(_on_time_span)
+
+
+def _fun_key(fun_name):
+    """A function's name as its trace event gives it: lowering and
+    compile events name the module ("jit(run_until)" or "jit_run_until")."""
+    if fun_name.startswith("jit(") and fun_name.endswith(")"):
+        return fun_name[4:-1]
+    return fun_name.removeprefix("jit_")
 
 
 # ---------------------------------------------------------------------------
@@ -43,35 +131,21 @@ _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 # ---------------------------------------------------------------------------
 
 
-class _NullSpan:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
-
-
 class NullProfiler:
-    """Inactive profiler: every hook is a constant-time no-op."""
+    """Inactive profiler: every hook is a constant-time no-op, except that
+    a span still opens a TraceAnnotation (one TraceMe check when no
+    `jax.profiler` trace is running)."""
 
     enabled = False
     sync = False
 
     def span(self, name, **args):
-        return _NULL_SPAN
+        return TraceAnnotation(name)
 
     def add_span(self, name, t0_abs, t1_abs, **args):
         pass
 
     def transfer(self, nbytes, count=1):
-        pass
-
-    def compile_event(self, dur_s):
         pass
 
     def counter_sample(self, values):
@@ -80,7 +154,6 @@ class NullProfiler:
 
 _NULL = NullProfiler()
 _active = _NULL
-_hook_installed = False
 
 
 def current():
@@ -93,31 +166,19 @@ def install(prof):
     restores the no-op).  Returns the now-active profiler."""
     global _active
     _active = prof if prof else _NULL
-    if _active.enabled:
-        _ensure_compile_hook()
     return _active
 
 
-def _ensure_compile_hook():
-    """Register ONE process-global JAX event listener that forwards
-    backend-compile durations to whatever profiler is active.  JAX has no
-    per-listener unregister, so the listener is permanent and dispatches
-    through `current()`."""
-    global _hook_installed
-    if _hook_installed:
-        return
-    try:
-        from jax._src import monitoring
-
-        def _on_event(event, dur_s, **kw):
-            p = _active
-            if p.enabled and event == _COMPILE_EVENT:
-                p.compile_event(dur_s)
-
-        monitoring.register_event_duration_secs_listener(_on_event)
-        _hook_installed = True
-    except Exception:  # noqa: BLE001 - compile attribution is best-effort
-        pass
+def spanned(name):
+    """Decorator: the call runs inside span `name` of the active
+    profiler."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with _active.span(name):
+                return fn(*args, **kwargs)
+        return inner
+    return wrap
 
 
 # ---------------------------------------------------------------------------
@@ -126,15 +187,18 @@ def _ensure_compile_hook():
 
 
 class _Span:
-    __slots__ = ("prof", "name", "args", "t0")
+    __slots__ = ("prof", "name", "args", "t0", "annot")
 
     def __init__(self, prof, name, args):
         self.prof = prof
         self.name = name
         self.args = args
         self.t0 = 0.0
+        self.annot = None
 
     def __enter__(self):
+        self.annot = TraceAnnotation(self.name)
+        self.annot.__enter__()
         self.t0 = time.perf_counter()
         return self
 
@@ -143,6 +207,7 @@ class _Span:
         t0 = self.t0
         p.events.append((self.name, t0 - p.t0,
                          time.perf_counter() - t0, self.args))
+        self.annot.__exit__(*exc)
         return False
 
 
@@ -168,10 +233,12 @@ class Profiler:
         self.sync = sync
         self.counters = counters
         self.t0 = time.perf_counter()
+        self.t0_wall = time.time()
         self.events = []        # (name, t_rel_s, dur_s, args|None)
         self.transfer_bytes = 0
         self.transfer_count = 0
-        self.compiles = []      # (t_rel_s, dur_s)
+        # compile_spans() entries that ended while this was installed
+        self.compile_spans = []
         self.counter_samples = []   # (t_rel_s, {name: value})
         self.kernelcount = None     # tools/kernelcount.py report|None
         self.extra_metrics = {}     # {name: number} via set_metric
@@ -206,9 +273,13 @@ class Profiler:
         self.transfer_bytes += int(nbytes)
         self.transfer_count += int(count)
 
-    def compile_event(self, dur_s):
-        self.compiles.append((time.perf_counter() - self.t0 - dur_s,
-                              float(dur_s)))
+    @property
+    def compiles(self):
+        """(t_rel_s, dur_s) of each backend compile (or persistent-cache
+        load) that ended while this profiler was installed."""
+        return [(s - self.t0_wall, e - s)
+                for ev, _f, s, e in list(self.compile_spans)
+                if ev == BACKEND_COMPILE]
 
     def counter_sample(self, values: dict):
         """Record a snapshot of (already-fetched) device counters."""
@@ -283,21 +354,31 @@ class Profiler:
                 "p95_ms": round(_pct(durs, 95) * 1e3, 3),
                 "max_ms": round(durs[-1] * 1e3, 3),
             }
+        compiles = self.compiles
+        by_fun = {}
+        for ev, fun, s, e in list(self.compile_spans):
+            row = by_fun.setdefault(_fun_key(fun), {})
+            kind = ev[len(_COMPILE_PREFIX):].removesuffix("_duration")
+            row[kind] = row.get(kind, 0) + 1
+            row["total_s"] = round(row.get("total_s", 0.0) + e - s, 6)
         out = {
             "wall_s": round(time.perf_counter() - self.t0, 3),
             "phases": phases,
             "transfers": {"bytes": self.transfer_bytes,
                           "count": self.transfer_count},
-            "compile": {"count": len(self.compiles),
-                        "total_s": round(sum(d for _t, d in self.compiles),
-                                         3)},
+            # `functions`: per function, how often each compile phase
+            # ran (jaxpr_trace, jaxpr_to_mlir_module, backend_compile)
+            # and their seconds -- a second trace of `run_until` is a
+            # recompile of the window loop.
+            "compile": {"count": len(compiles),
+                        "total_s": round(sum(d for _t, d in compiles), 3),
+                        "functions": by_fun},
             # Flat aliases for benchdiff gating (tools/benchdiff.py):
             # "compiles" is a graph property (0-tolerance -- a new
             # compile in a sweep means a shape bucket broke), while
             # "compile_ms" is machine-bound wall time.
-            "compiles": len(self.compiles),
-            "compile_ms": round(
-                sum(d for _t, d in self.compiles) * 1e3, 1),
+            "compiles": len(compiles),
+            "compile_ms": round(sum(d for _t, d in compiles) * 1e3, 1),
         }
         dev = [(t, t + d) for n, t, d, _a in self.events
                if n in ("device_step", "device_window")]

@@ -1,49 +1,266 @@
-"""Phase-level profile of the engine micro-step: the one supported
-slope/ablation harness (consolidates the former stepprof, stepprof2 and
-stepprof_onion scripts).
+"""Device time of the window loop per phase, from a profiler trace.
 
-Two attribution methods over the same busy-state worlds:
+Every op the engine compiles carries its phase in its `op_name`
+metadata: the innermost name of `shadow1_tpu.trace.PHASES` (exchange,
+scan, bounds, close, rx, tcp_timers, app, tcp_tx, stage, tx, cpu,
+mesh_min) on its name stack.  This tool runs one of its worlds for a few
+launches under `jax.profiler.trace`, then reduces the device's op events
+to self time per phase, and prints what no phase covers:
 
-* subsets -- time while-loops of increasing phase subsets (slope method,
-  50 vs 200 iterations); each phase's cost is the delta from the
-  previous subset.  Fast, but partial graphs can fuse differently than
-  the real step.
-* ablate -- time the FULL micro-step with single phases no-op'd
-  (monkeypatched before trace), so each phase's cost is a delta from the
-  same full-step baseline.  Slower, more faithful.
-* fused -- the megakernel path (core/megakernel.py): fused step vs
-  reference step, per-kernel compute deltas (bodies no-op'd inside the
-  launch structure), the boundary exchange both ways, and the whole
-  window both ways (K_WINDOW persistent kernel vs the inline
-  main-graph window body).
-
-Also times the window-boundary exchange as its own forced loop.
+* `unscoped`: ops of the window loop no phase covers: the loops' own
+  `while` ops, ops in computations that mix phases;
+* `other`: ops of no program in the map (transfers, small programs).
 
     python tools/phaseprof.py --world phold --hosts 16384
-    python tools/phaseprof.py --world onion --circuits 2000 --method ablate
+    python tools/phaseprof.py --world onion --circuits 2000 --warm-ms 300
+    python tools/phaseprof.py --world phold --hosts 65536 --devices 4
 
-For whole-run wall-time attribution (device launches vs drains vs
-compiles) use `--profile` on the CLI or trace.Profiler instead; this
-tool is for intra-step phase cost on a live backend.
+An op's phase comes from the compiled program's HLO text
+(`hlo_phases`): the name stack in its `metadata={op_name=...}`, or, for
+an op XLA made while compiling (no `op_name`: a sort's expansion, a
+copy), the one phase the ops it fuses, or the ops of its computation,
+share.  The trace's op events are matched to it by HLO name (the
+`%fusion.12 = ...` line a TPU's `XLA Ops` event is named by, the CPU's
+`hlo_op` stat).  A reduction of a trace that has no such map can read
+a TPU op's name stack from the `tf_op` stat of the op's event metadata,
+but that stat is missing for the ops XLA made (8-14% of busy time).
+An executable loaded from a compile cache that an older build wrote
+carries that build's name stacks: the cache key leaves metadata out
+(jax's `jax_compilation_cache_include_metadata_in_key`).
 """
 
 from __future__ import annotations
 
 import argparse
+import collections
+import glob
+import json
+import os
+import re
+import sys
+import tempfile
 import time
 
-import numpy as np
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if _ROOT not in sys.path:
+    sys.path.insert(0, _ROOT)
 
-import shadow1_tpu  # noqa: F401  (x64)
-import jax
-import jax.numpy as jnp
+import shadow1_tpu  # noqa: E402,F401  (x64)
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
 
-from shadow1_tpu import sim
-from shadow1_tpu.core import emit, engine, simtime
+from shadow1_tpu import sim, trace  # noqa: E402
+from shadow1_tpu.core import emit, engine, simtime  # noqa: E402
 
 I32, I64 = jnp.int32, jnp.int64
 SEC = simtime.SIMTIME_ONE_SECOND
 MS = simtime.SIMTIME_ONE_MILLISECOND
+
+_COMP = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
+_INSTR = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s*=(.*)$")
+_OP_NAME = re.compile(r"metadata=\{[^}]*?op_name=\"([^\"]*)\"")
+_CALLS = re.compile(r"calls=%?([\w.\-]+)")
+_REF = re.compile(r"%([\w.\-]+)")
+
+
+def hlo_phases(hlo_text):
+    """{HLO instruction: phase or None} of a compiled program.  An
+    instruction's phase is the innermost PHASES name on its `op_name`.
+    One with no `op_name` at all -- XLA made it while compiling: a sort's
+    expansion, a copy, a fusion rooted in such an op -- takes the one
+    phase the named instructions it fuses share, else that of the
+    nearest named instructions that consume its result (the most common
+    one among the nearest).  What is left (the loops' own `while` ops)
+    is None."""
+    own, calls, users, members = {}, {}, {}, {}
+    named = set()
+    cur = None
+    for line in hlo_text.splitlines():
+        m = _COMP.match(line)
+        if m:
+            cur = m.group(1)
+            members[cur] = []
+            continue
+        m = _INSTR.match(line)
+        if m is None or cur is None:
+            continue
+        name, rest = m.groups()
+        op = _OP_NAME.search(rest)
+        if op:
+            named.add(name)
+        own[name] = phase_of(op.group(1)) if op else None
+        members[cur].append(name)
+        c = _CALLS.search(rest)
+        if c:
+            calls[name] = c.group(1)
+        for ref in _REF.findall(rest.split(", metadata=", 1)[0]):
+            users.setdefault(ref, []).append(name)
+
+    def fused(name):
+        found = {own[n] for n in members.get(calls.get(name), ())
+                 if n in named}
+        return found.pop() if len(found) == 1 else None
+
+    def consumers(name):
+        seen, level = {name}, users.get(name, [])
+        while level:
+            found = collections.Counter(own[u] for u in level
+                                        if u in named and own[u])
+            if found:
+                return found.most_common(1)[0][0]
+            seen.update(level)
+            level = [u for n in level if n not in named
+                     for u in users.get(n, []) if u not in seen]
+        return None
+
+    return {n: own[n] if n in named else fused(n) or consumers(n)
+            for n in own}
+
+
+def phase_of(op_name):
+    """The innermost PHASES name on an op_name's name stack, or None."""
+    for part in reversed(op_name.rstrip(":").split("/")):
+        if part in trace.PHASES:
+            return part
+    return None
+
+
+def load_ops(trace_dir):
+    """{device: [(HLO op, start_ns, end_ns)]}: the op events of the
+    newest `.xplane.pb` under a `jax.profiler.trace` dir.  A device is a
+    `/device:*` plane's `XLA Ops` line, whose events are named by their
+    HLO line ("%fusion.12 = s32[...] ..."); on the CPU it is the host
+    plane's events that carry an `hlo_op` stat."""
+    from jax.profiler import ProfileData
+    files = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    if not files:
+        raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
+    with open(max(files, key=os.path.getmtime), "rb") as f:
+        raw = f.read()
+    out = {}
+    for plane in ProfileData.from_serialized_xspace(raw).planes:
+        on_device = plane.name.startswith("/device:")
+        for line in plane.lines:
+            if on_device and line.name != "XLA Ops":
+                continue
+            for e in line.events:
+                if on_device:
+                    out.setdefault(plane.name, []).append(
+                        (e.name.split(" = ", 1)[0].lstrip("%"),
+                         e.start_ns, e.end_ns))
+                    continue
+                hlo_op = dict(e.stats).get("hlo_op")
+                if hlo_op is not None:
+                    out.setdefault("cpu", []).append(
+                        (hlo_op, e.start_ns, e.end_ns))
+    return out
+
+
+def self_times(ops):
+    """[(op, self_ns)]: each op's duration less that of the ops nested
+    in it (a `while` spans its body's ops on the op line)."""
+    evs = sorted(ops, key=lambda o: (o[1], -o[2]))
+    own = [b - a for _n, a, b in evs]
+    stack = []
+    for i, (_n, a, b) in enumerate(evs):
+        while stack and evs[stack[-1]][2] <= a:
+            stack.pop()
+        if stack and b <= evs[stack[-1]][2]:
+            own[stack[-1]] -= b - a
+        stack.append(i)
+    return [(evs[i], own[i]) for i in range(len(evs))]
+
+
+def phase_table(ops_by_device, phases):
+    """{device: {phase|"unscoped"|"other": self seconds}} of load_ops'
+    events, each op under its phase in the program's `hlo_phases` map:
+    "unscoped" where the map names none, "other" for an op of no program
+    in the map."""
+    table = {}
+    for dev, ops in sorted(ops_by_device.items()):
+        row = {}
+        for (op, _a, _b), ns in self_times(ops):
+            key = (phases[op] or "unscoped") if op in phases else "other"
+            row[key] = row.get(key, 0.0) + ns / 1e9
+        table[dev] = row
+    return table
+
+
+def format_table(table, steps=None):
+    lines = []
+    for dev, row in table.items():
+        busy = sum(row.values())
+        lines.append(f"{dev}: busy {busy:.6f} s"
+                     + (f", {steps} micro-steps" if steps else ""))
+        for key in (*trace.PHASES, "unscoped", "other"):
+            if key in row:
+                per = (f"  {row[key] / steps * 1e6:10.1f} us/step"
+                       if steps else "")
+                lines.append(f"  {key:<11s} {row[key]:12.6f} s "
+                             f"{100 * row[key] / busy:6.2f}%{per}")
+    return "\n".join(lines)
+
+
+def _build(args):
+    if args.world == "phold":
+        state, params, app = sim.build_phold(
+            num_hosts=args.hosts, msgs_per_host=4,
+            mean_delay_ns=10 * MS, stop_time=10 * SEC,
+            pool_capacity=args.hosts * 8, rx_batch=2)
+        warm_t = args.warm_ms * MS
+    else:
+        state, params, app = sim.build_onion(
+            num_circuits=args.circuits, bytes_per_circuit=1 << 20,
+            pool_slab=64, stop_time=120 * SEC)
+        warm_t = args.warm_ms * MS
+    state, params = jax.device_put((state, params), jax.devices()[0])
+    return state, params, app, warm_t
+
+
+def _compiled_text(state, params, app, t, devices):
+    """The HLO text of the program `sim.run` launches for this world."""
+    if devices <= 1:
+        return engine.run_until.lower(state, params, app, t).compile() \
+            .as_text()
+    from shadow1_tpu import parallel
+    from shadow1_tpu.parallel import mesh as mesh_mod
+    mesh = parallel.make_mesh(jax.devices()[:devices])
+    state, params = parallel.pad_world_to_mesh(state, params, devices)
+    sspecs = mesh_mod._state_specs(state)
+    pspecs = mesh_mod._param_specs(params)
+    fn = mesh_mod._build(app, mesh, sspecs, pspecs)
+    state, params = mesh_mod._place(mesh, (state, params), (sspecs, pspecs))
+    with mesh:
+        return fn.lower(state, params, jnp.asarray(t, I64)).compile() \
+            .as_text()
+
+
+def profile_world(args):
+    """Warm to `warm_ms`, then trace `launches` launches of `chunk_ms`;
+    returns (table, micro-steps in the traced launches)."""
+    state, params, app, t = _build(args)
+    kw = {"devices": args.devices} if args.devices > 1 else {}
+    state = jax.block_until_ready(sim.run(state, params, app, until=t,
+                                          **kw))
+    print(f"world={args.world} hosts={state.hosts.num_hosts} "
+          f"devices={args.devices} warm to {t / SEC:g} sim-s",
+          file=sys.stderr)
+    phases = hlo_phases(_compiled_text(state, params, app,
+                                       t + args.chunk_ms * MS,
+                                       args.devices))
+    steps0 = int(state.n_steps)
+    trace_dir = tempfile.mkdtemp(prefix="phaseprof-")
+    t0 = time.perf_counter()
+    with jax.profiler.trace(trace_dir):
+        for _ in range(args.launches):
+            t += args.chunk_ms * MS
+            state = jax.block_until_ready(
+                sim.run(state, params, app, until=t, **kw))
+    print(f"{args.launches} launches in {time.perf_counter() - t0:.3f} s "
+          f"(traced)", file=sys.stderr)
+    return (phase_table(load_ops(trace_dir), phases),
+            int(state.n_steps) - steps0)
 
 
 def timeloop(name, state0, params, app, body, iters_pair=(50, 200),
@@ -67,13 +284,13 @@ def timeloop(name, state0, params, app, body, iters_pair=(50, 200),
         jf = jax.jit(run)
         th0, _ = engine._scan_all(state0, params, app)
         out = jf(state0, th0)
-        np.asarray(out[1].now)
+        jax.block_until_ready(out[1].now)
         ts = []
         for trial in range(trials):
             st2 = state0.replace(now=state0.now + trial)
             t0 = time.perf_counter()
             out = jf(st2, th0)
-            np.asarray(out[1].now)
+            jax.block_until_ready(out[1].now)
             ts.append(time.perf_counter() - t0)
         res[iters] = min(ts)
     slope = (res[iters_pair[1]] - res[iters_pair[0]]) \
@@ -81,256 +298,6 @@ def timeloop(name, state0, params, app, body, iters_pair=(50, 200),
     if not quiet:
         print(f"{name:44s} {slope:8.3f} ms/iter", flush=True)
     return slope
-
-
-def _build(args):
-    if args.world == "phold":
-        state, params, app = sim.build_phold(
-            num_hosts=args.hosts, msgs_per_host=4,
-            mean_delay_ns=10 * MS, stop_time=10 * SEC,
-            pool_capacity=args.hosts * 8, rx_batch=2)
-        warm_t = 50 * MS
-        we = jnp.asarray(10 * SEC, I64)
-    else:
-        state, params, app = sim.build_onion(
-            num_circuits=args.circuits, bytes_per_circuit=1 << 20,
-            pool_slab=64, stop_time=120 * SEC)
-        # Into the busy phase: clients started, streams flowing.
-        warm_t = args.warm_ms * MS
-        we = jnp.asarray(120 * SEC, I64)
-    state = engine.run_until(state, params, app, warm_t)
-    jax.block_until_ready(state)
-    print(f"world={args.world} hosts={state.hosts.num_hosts} "
-          f"steps_so_far={int(state.n_steps)}")
-    return state, params, app, we
-
-
-def _subset_bodies(state, params, app, we):
-    """(name, body) pairs of increasing phase subsets, world-aware."""
-    h = state.hosts.num_hosts
-    uses_tcp = engine._uses_tcp(app)
-    if uses_tcp:
-        from shadow1_tpu.transport import tcp as tcp_mod
-        n_lanes = emit.NUM_SLOTS + max(0, int(getattr(app, "rx_batch", 1))
-                                       - 1)
-    else:
-        n_lanes = emit.SLOT_APP + max(1, int(getattr(app, "app_tx_lanes",
-                                                     1)))
-
-    def scan(s):
-        return engine._scan_all(s, params, app)
-
-    def base(s, th):
-        active = th < we
-        tick = jnp.where(active, th, we)
-        return s, emit.empty(h, n_lanes, cols=s.pool.blk.shape[1]), \
-            tick, active
-
-    def v_scan(s, th):
-        s = s.replace(hosts=s.hosts.replace(
-            t_resume=jnp.minimum(s.hosts.t_resume, th)))
-        th2, _ = scan(s)
-        return s, th2
-
-    def stack(*stages):
-        """Body running rx + the given post-rx stages, then scan."""
-        def body(s, th):
-            s, em, tick, active = base(s, th)
-            s, em, _d, tp = engine._rx_phase(s, params, em, tick, active,
-                                             app, we)
-            for st in stages:
-                s, em = st(s, em, tp, active)
-            th2, _ = scan(s)
-            return s, th2
-        return body
-
-    def s_app(s, em, tp, active):
-        if getattr(app, "wants_window_end", False):
-            return app.on_tick(s, params, em, tp, active, window_end=we)
-        return app.on_tick(s, params, em, tp, active)
-
-    def s_stage(s, em, tp, active):
-        s, _p = engine._stage_emissions(s, params, em, tp, active, app)
-        return s, em
-
-    def v_full(s, th):
-        s = engine._microstep_core(s, params, app, th, we)
-        th2, _ = scan(s)
-        return s, th2
-
-    out = [("scan only", v_scan), ("+ rx_phase", stack())]
-    if uses_tcp:
-        def s_timers(s, em, tp, active):
-            return tcp_mod.run_timers(s, params, em, tp, active)
-
-        def s_tx(s, em, tp, active):
-            return tcp_mod.transmit(s, params, em, tp, active)
-
-        out += [("+ tcp timers", stack(s_timers)),
-                ("+ app on_tick", stack(s_timers, s_app)),
-                ("+ tcp transmit", stack(s_timers, s_app, s_tx)),
-                ("+ stage_emissions", stack(s_timers, s_app, s_tx,
-                                            s_stage))]
-    else:
-        out += [("+ app on_tick", stack(s_app)),
-                ("+ stage_emissions", stack(s_app, s_stage))]
-    out.append(("full microstep (+tx_drain)", v_full))
-    return out
-
-
-def run_subsets(state, params, app, we):
-    t = {}
-    prev = None
-    for name, body in _subset_bodies(state, params, app, we):
-        t[name] = timeloop(name, state, params, app, body)
-        if prev is not None:
-            print(f"{'':44s} {t[name] - prev:+8.3f} delta")
-        prev = t[name]
-    return t
-
-
-def run_ablate(state, params, app, we):
-    """Full-step baseline minus single-phase no-ops (patched before
-    trace), so each cost is a delta from the SAME fused graph."""
-    def v_full(s, th):
-        s = engine._microstep_core(s, params, app, th, we)
-        th2, _ = engine._scan_all(s, params, app)
-        return s, th2
-
-    base = timeloop("full microstep + scan", state, params, app, v_full)
-
-    def with_patches(patches):
-        saved = {name: getattr(engine, name) for name in patches}
-        for name, fn in patches.items():
-            setattr(engine, name, fn)
-        try:
-            return timeloop(f"full - {'/'.join(patches)}", state, params,
-                            app, v_full)
-        finally:
-            for name, fn in saved.items():
-                setattr(engine, name, fn)
-
-    no_tx = with_patches({"_tx_drain":
-                          lambda s, params, tick_t, active, **kw: s})
-    no_stage = with_patches({"_stage_emissions":
-                             lambda s, params, em, tick_t, active, app,
-                             **kw: (s, jnp.zeros_like(em.valid))})
-    no_rx = with_patches({"_rx_phase":
-                          lambda s, params, em, tick_t, active, app, we2,
-                          **kw: (s, em, jnp.zeros(
-                              (s.hosts.num_hosts,), I32), tick_t)})
-
-    print(f"{'=> tx_drain':44s} {base - no_tx:8.3f} ms")
-    print(f"{'=> stage_emissions':44s} {base - no_stage:8.3f} ms")
-    print(f"{'=> rx_phase':44s} {base - no_rx:8.3f} ms")
-
-
-def run_fused(state, params, app, we):
-    """Fused-phase attribution (--method fused): slope-time the fused
-    micro-step (megakernel.microstep_fused) against the reference step,
-    then re-time it with single kernel BODIES no-op'd -- the launch
-    structure stays, the block compute goes -- so each kernel's compute
-    cost is a delta from the same fused graph.  The all-bodies-no-op
-    loop is what's left: kernel launch overhead + the between-kernel
-    islands (timers/app tick) + scan glue.  Finishes with the boundary
-    exchange both ways (reference graph vs single-block kernel)."""
-    from shadow1_tpu.core import megakernel as mk
-    pf = params.replace(megakernel=True)
-    pr = params.replace(megakernel=False)
-    if not mk.enabled(state, pf, app):
-        print("fused: megakernel path disabled for this world "
-              "(log/cap ring installed?); nothing to time")
-        return
-
-    def v_ref(s, th):
-        s = engine._microstep_core(s, pr, app, th, we)
-        th2, _ = engine._scan_all(s, pr, app)
-        return s, th2
-
-    def v_fused(s, th):
-        s2, th2, _g = mk.microstep_fused(s, pf, app, th, we)
-        return s2, th2
-
-    ref = timeloop("reference microstep + scan", state, params, app,
-                   v_ref)
-    base = timeloop("fused microstep (all kernels)", state, params, app,
-                    v_fused)
-    print(f"{'=> fused vs reference':44s} {base - ref:+8.3f} ms/iter")
-
-    def with_patches(label, patches):
-        saved = {name: getattr(engine, name) for name in patches}
-        for name, fn in patches.items():
-            setattr(engine, name, fn)
-        try:
-            return timeloop(label, state, params, app, v_fused)
-        finally:
-            for name, fn in saved.items():
-                setattr(engine, name, fn)
-
-    def _id_rx(s, params2, em, tick_t, active, app2, we2, **kw):
-        return s, em, jnp.zeros((s.hosts.num_hosts,), I32), tick_t
-
-    def _id_stage(s, params2, em, tick_t, active, app2, **kw):
-        return s, jnp.zeros_like(em.valid)
-
-    def _id_drain(s, *a, **kw):
-        return s
-
-    no_rx = with_patches("fused - deliver body", {"_rx_phase": _id_rx})
-    no_tx = with_patches("fused - transport body",
-                         {"_stage_emissions": _id_stage,
-                          "_tx_drain_body": _id_drain})
-    hollow = with_patches("fused - all kernel bodies",
-                          {"_rx_phase": _id_rx,
-                           "_stage_emissions": _id_stage,
-                           "_tx_drain_body": _id_drain})
-    print(f"{'=> K_DELIVER compute':44s} {base - no_rx:8.3f} ms")
-    print(f"{'=> K_TRANSPORT compute':44s} {base - no_tx:8.3f} ms")
-    print(f"{'=> islands + launches + scan (residual)':44s} "
-          f"{hollow:8.3f} ms")
-
-    def v_exch_ref(s, th):
-        s = engine._exchange_body(s, pr)
-        return s.replace(now=s.now + 1), th
-
-    def v_exch_fused(s, th):
-        s = engine._exchange_body(s, pf, fused=True)
-        return s.replace(now=s.now + 1), th
-
-    er = timeloop("exchange reference (forced)", state, params, app,
-                  v_exch_ref)
-    ef = timeloop("exchange single-block kernel (forced)", state, params,
-                  app, v_exch_fused)
-    print(f"{'=> exchange kernel vs reference':44s} {ef - er:+8.3f} "
-          f"ms/iter")
-
-    # Whole-window attribution: K_WINDOW (the persistent window kernel)
-    # runs the complete window body -- exchange, micro-step loop,
-    # netem advance, bookkeeping -- inside ONE Pallas region, where the
-    # main-graph row traces the identical body inline.  The delta is
-    # what collapsing a window's dispatch to a single launch buys (or
-    # costs) on this backend.  Windows are heavier than micro-steps, so
-    # the slope pair is shorter.
-    pp = pf.replace(persistent=True)
-    if not mk.persistent_enabled(state, pp, app):
-        print("fused: persistent window kernel disabled for this world "
-              "(mesh halo offsets installed?); skipping K_WINDOW rows")
-        return
-
-    def v_win_ref(s, th):
-        s2, th2, _g, _ws, _wend = engine._window_body_ref(s, pr, app, we)
-        return s2, th2
-
-    def v_win_fused(s, th):
-        s2, th2, _g, _ws, _wend = mk.window_fused(s, pp, app, we)
-        return s2, th2
-
-    wr = timeloop("window body main-graph (forced)", state, params, app,
-                  v_win_ref, iters_pair=(10, 40))
-    wf = timeloop("K_WINDOW persistent kernel (forced)", state, params,
-                  app, v_win_fused, iters_pair=(10, 40))
-    print(f"{'=> K_WINDOW vs main-graph window':44s} {wf - wr:+8.3f} "
-          f"ms/window")
 
 
 def measure_staging_ms(state, params, app, iters_pair=(20, 60)) -> float:
@@ -356,16 +323,6 @@ def measure_staging_ms(state, params, app, iters_pair=(20, 60)) -> float:
                     iters_pair=iters_pair, quiet=True)
 
 
-def run_exchange(state, params, app):
-    def v_exch(s, th):
-        s = engine._exchange_body(s, params)
-        # data dependence so iterations don't collapse
-        s = s.replace(now=s.now + 1)
-        return s, th
-
-    timeloop("exchange_body (forced)", state, params, app, v_exch)
-
-
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--world", choices=("phold", "onion"), default="phold")
@@ -373,22 +330,22 @@ def main(argv=None):
                     help="phold world size")
     ap.add_argument("--circuits", type=int, default=2000,
                     help="onion world size (hosts = 5 x circuits)")
-    ap.add_argument("--warm-ms", type=int, default=500,
-                    help="sim-ms to advance before timing (busy state)")
-    ap.add_argument("--method",
-                    choices=("subsets", "ablate", "fused", "both"),
-                    default="subsets")
+    ap.add_argument("--devices", type=int, default=1,
+                    help="shard the world over this many devices")
+    ap.add_argument("--warm-ms", type=int, default=250,
+                    help="sim-ms to advance before tracing")
+    ap.add_argument("--chunk-ms", type=int, default=250,
+                    help="sim-ms a traced launch covers")
+    ap.add_argument("--launches", type=int, default=2,
+                    help="launches to trace")
+    ap.add_argument("--json", action="store_true",
+                    help="print the table as JSON")
     args = ap.parse_args(argv)
-
-    state, params, app, we = _build(args)
-    if args.method in ("subsets", "both"):
-        run_subsets(state, params, app, we)
-    if args.method in ("ablate", "both"):
-        run_ablate(state, params, app, we)
-    if args.method in ("fused", "both"):
-        run_fused(state, params, app, we)
-    if args.method != "fused":
-        run_exchange(state, params, app)
+    table, steps = profile_world(args)
+    if args.json:
+        print(json.dumps({"table": table, "steps": steps}))
+    else:
+        print(format_table(table, steps))
 
 
 if __name__ == "__main__":
