@@ -401,10 +401,11 @@ def _outbox_pending(state: SimState):
 
 
 def _superblock(n: int, h: int) -> int:
-    """Items per rank superblock.  Memory: the pairwise rank cube is
-    n*M bytes and the per-block count table is (n/M)*h*4 bytes, so the
-    sweet spot is M ~ sqrt(4h); clamp to [64, 512] and keep both sides
-    bounded at 10k-host scale (n can exceed a million items)."""
+    """Items per rank superblock of the mesh exchange's `_rank_by_dst`.
+    Memory: the pairwise rank cube is n*M bytes and the per-block count
+    table is (n/M)*h*4 bytes, so the sweet spot is M ~ sqrt(4h); clamp
+    to [64, 512] and keep both sides bounded at 10k-host scale (n can
+    exceed a million items)."""
     m = int((4 * max(h, 1)) ** 0.5)
     m = max(64, min(512, (m // 64) * 64 if m >= 64 else 64))
     return min(m, max(64, n))
@@ -413,7 +414,10 @@ def _superblock(n: int, h: int) -> int:
 def _rank_by_dst(mask, dstp, h, m):
     """Per-item rank among masked same-destination items, in flat order
     (hierarchical: scatter-add superblock counts + exclusive cumsum +
-    in-superblock pairwise ranks).  Returns ([npad] rank, [H] totals)."""
+    in-superblock pairwise ranks).  Returns ([npad] rank, [H] totals).
+    The mesh exchange's ranking (send buckets by shard, then received
+    rows by local destination); the single-device core ranks by a keyed
+    sort instead (`_exchange_core`)."""
     npad = dstp.shape[0]
     blkid = jnp.arange(npad, dtype=I32) // m
     b = npad // m
@@ -430,42 +434,76 @@ def _rank_by_dst(mask, dstp, h, m):
     return off.reshape(-1)[blkid * h + dstp] + rank_in, total
 
 
-def _exchange_core(pool, ib, h, params, ret_islot=False):
+def _keyed_order(key, idx):
+    """Stable sort of the flat row indices `idx` by i32 `key`: returns
+    (sorted keys, source row of each sorted position).  Equal keys keep
+    flat order."""
+    return jax.lax.sort((key, idx), num_keys=1, is_stable=True)
+
+
+def _seg_starts(keys, bounds):
+    """Sorted position of the first key >= each of `bounds` (i32), for
+    sorted i32 `keys`.  Two levels instead of a binary search's ~20
+    dependent gathers (each a device op of its own): count the 128-key
+    blocks that lie wholly below each bound (one compare against the
+    block maxima), then the keys below it in the one block where it
+    falls (one row gather)."""
+    n = keys.shape[0]
+    nb = -(-n // 128)
+    k2 = jnp.pad(keys, (0, nb * 128 - n),
+                 constant_values=jnp.iinfo(jnp.int32).max).reshape(nb, 128)
+    blk = jnp.sum(k2[None, :, -1] < bounds[:, None], axis=1, dtype=I32)
+    row = k2[jnp.minimum(blk, nb - 1)]
+    inrow = jnp.sum(row < bounds[:, None], axis=1, dtype=I32)
+    return jnp.minimum(blk * 128 + jnp.where(blk < nb, inrow, 0), n)
+
+
+def _exchange_core(pool, ib, h, params, ret_slots=False):
     """Slab machinery of the boundary exchange, free of SimState
-    packaging: rank movers by destination, splice them into inbox free
-    slots, clear the outbox stage.  Returns (pool, inbox, total,
+    packaging: order movers by destination, deliver them into inbox
+    free slots, clear the outbox stage.  Returns (pool, inbox, total,
     total_prot, n_free) -- the three [H] per-destination tallies are
     what the accounting tail (_exchange_body) derives drops, trace
-    counters and recorder rows from.  `ret_islot` (lineage tracing)
-    appends the slot-assignment internals (islot, ok, mvp, pad) so the
-    tail can move trace ids under the identical permutation.
+    counters and recorder rows from.  `ret_slots` (lineage tracing)
+    appends (take, row, ok): the [P1] slot map (slot takes a mover /
+    the mover's outbox row) and the [P0] placed-mover mask in flat
+    order, so the tail can move trace ids under the identical map.
+
+    Two steps; neither builds a table of hosts x row blocks:
+
+    1. ORDER: one stable sort of the outbox rows keyed by destination
+       (non-movers past every destination).  Each destination's movers
+       form one segment of the sorted order, in flat order; segment
+       starts and per-destination totals are read off the sorted keys.
+    2. DELIVER: destination-side.  The j-th free slot of destination d
+       (ascending slot order) takes the mover at sorted position
+       start[d] + j when j < total[d]; every slab is one row gather of
+       the outbox plus a `where` against its old bytes, so slots that
+       take no mover keep theirs (stale bytes included).
 
     Split out so the megakernel path can run it as ONE single-block
     pallas call (megakernel.exchange_call): every op here is integer
     slab shuffling, so it is fusion-context stable (see the "f32
     stability" section of docs/megakernel.md)."""
     p0 = pool.capacity
-    p1 = ib.capacity
-    ki = p1 // h
+    ki = ib.capacity // h
     ic = ib.blk.shape[1]          # ICOLS, or NCOLS_UDP for TCP-free worlds
 
     moving = pool.stage == STAGE_IN_FLIGHT             # [P0], src-major order
     dst = jnp.clip(pool.dst, 0, h - 1)
+    idx = jnp.arange(p0, dtype=I32)
 
-    # --- per-item rank among same-destination movers, in flat (src-major)
-    # order.  Flat order == (src, emission counter) order within a window
+    # --- movers ordered by destination, flat (src-major) order within
+    # one.  Flat order == (src, emission counter) order within a window
     # because outbox slots free only at boundaries, so allocation indices
     # are monotone across the window's micro-steps -- this reproduces the
     # reference's (srcHostID, srcHostEventID) tiebreak (event.c:110-153).
-    m = _superblock(p0, h)
-    npad = -(-p0 // m) * m
-    pad = npad - p0
-    dstp = jnp.pad(dst, (0, pad))
-    mvp = jnp.pad(moving, (0, pad))
-    rank, total = _rank_by_dst(mvp, dstp, h, m)
+    keys, src = _keyed_order(jnp.where(moving, dst, h).astype(I32), idx)
+    bnd = _seg_starts(keys, jnp.arange(h + 1, dtype=I32))
+    start = bnd[:h]                                     # [H] segment starts
+    total = bnd[1:] - start                             # [H] movers per dst
 
     free2 = (ib.stage == STAGE_FREE).reshape(h, ki)
-    ids = jnp.arange(ki, dtype=I32)[None, :]
     n_free = jnp.sum(free2, axis=1, dtype=I32)          # [H]
 
     # --- ACK-before-data shedding (TCP worlds, overflow windows only):
@@ -474,35 +512,36 @@ def _exchange_core(pool, ib, h, params, ret_islot=False):
     # router pressure.  Cumulative ACKing absorbs the loss (the next ACK
     # supersedes the shed one), so only DATA/control drops are protocol-
     # visible and only they raise ERR_POOL_OVERFLOW.  Implemented as a
-    # class-aware re-rank: protected movers keep their rank among
-    # protected; pure ACKs rank after all protected for that dst.  Slot
-    # positions don't affect delivery order ((time, pkt_id) row-min), so
-    # the re-rank changes only WHO overflows, deterministically.
+    # class-keyed re-sort: within a destination, protected movers come
+    # first in flat order, then pure ACKs in flat order (segment starts
+    # are unchanged).  Slot positions don't affect delivery order
+    # ((time, pkt_id) row-min), so the re-order changes only WHO
+    # overflows, deterministically.
     if ic >= ICOLS:
         blk_f = pool.blk
         from ..transport.tcp import pure_ack as _pure_ack
-        pure_ack = _pure_ack(blk_f[:, ICOL_PROTO], blk_f[:, ICOL_FLAGS],
-                             blk_f[:, ICOL_LEN])
-        ackp = jnp.pad(pure_ack, (0, pad)) & mvp
+        ack = _pure_ack(blk_f[:, ICOL_PROTO], blk_f[:, ICOL_FLAGS],
+                        blk_f[:, ICOL_LEN]) & moving
         overflow = jnp.any(total > n_free)
 
         def two_class(_):
-            rank_prot, total_prot = _rank_by_dst(mvp & ~ackp, dstp, h, m)
-            r = jnp.where(ackp, total_prot[dstp] + (rank - rank_prot),
-                          rank_prot)
-            return r, total_prot
+            k2, src2 = _keyed_order(
+                jnp.where(moving, 2 * dst + ack, 2 * h).astype(I32), idx)
+            prot_end = _seg_starts(k2, 2 * jnp.arange(h, dtype=I32) + 1)
+            return src2, prot_end - start
 
-        rank_eff, total_prot = jax.lax.cond(
-            overflow & jnp.any(ackp), two_class,
-            lambda _: (rank, total), None)
+        src, total_prot = jax.lax.cond(
+            overflow & jnp.any(ack), two_class,
+            lambda _: (src, total), None)
     else:
-        rank_eff, total_prot = rank, total
+        total_prot = total
 
-    # --- destination slab free-slot assignment (ascending slot order).
-    order2 = jnp.argsort(jnp.where(free2, ids, ids + ki), axis=1).astype(I32)
-    within = order2.reshape(-1)[dstp * ki + jnp.clip(rank_eff, 0, ki - 1)]
-    ok = mvp & (rank_eff < n_free[dstp])
-    islot = jnp.where(ok, dstp * ki + within, p1)       # p1 = drop sentinel
+    # --- destination-side slot map: free rank j of each slot (exclusive
+    # count of free slots before it in its slab), and the outbox row of
+    # the mover of effective rank j, if there is one.
+    fr = jnp.cumsum(free2, axis=1, dtype=I32) - free2
+    take = (free2 & (fr < total[:, None])).reshape(-1)          # [P1]
+    row = src[jnp.clip(start[:, None] + fr, 0, p0 - 1)].reshape(-1)
 
     # --- forward the packed rows verbatim: the outbox block's first `ic`
     # columns ARE the inbox layout; only the TIME columns need splicing
@@ -512,13 +551,11 @@ def _exchange_core(pool, ib, h, params, ret_islot=False):
         [pool.blk[:, :ICOL_TIME_LO],
          enc_lo(pool.time)[:, None], enc_hi(pool.time)[:, None],
          pool.blk[:, ICOL_TIME_HI + 1:ic]], axis=1)       # [P0, ic]
-    vals = jnp.pad(vals, ((0, pad), (0, 0)))              # [npad, ic]
 
     ib = ib.replace(
-        blk=ib.blk.at[islot].set(vals, mode="drop"),
-        stage=ib.stage.at[islot].set(STAGE_IN_FLIGHT, mode="drop"),
-        status=ib.status.at[islot].set(jnp.pad(pool.status, (0, pad)),
-                                       mode="drop")
+        blk=jnp.where(take[:, None], vals[row], ib.blk),
+        stage=jnp.where(take, STAGE_IN_FLIGHT, ib.stage),
+        status=jnp.where(take, pool.status[row], ib.status)
         if params.pds_trail else ib.status,
     )
 
@@ -526,8 +563,12 @@ def _exchange_core(pool, ib, h, params, ret_islot=False):
     # overflowed (and whether it was a shed ACK or a counted drop) is
     # the accounting tail's business, derived from the tallies below.
     pool = pool.replace(stage=jnp.where(moving, STAGE_FREE, pool.stage))
-    if ret_islot:
-        return pool, ib, total, total_prot, n_free, (islot, ok, mvp, pad)
+    if ret_slots:
+        # Placed movers in flat order: effective rank (sorted position
+        # less segment start) below the destination's free slots.
+        pos = jnp.zeros((p0,), I32).at[src].set(idx)     # inverse order
+        ok = moving & (pos - start[dst] < n_free[dst])
+        return pool, ib, total, total_prot, n_free, (take, row, ok)
     return pool, ib, total, total_prot, n_free
 
 
@@ -544,35 +585,33 @@ def _exchange_body(state: SimState, params, fused: bool = False) -> SimState:
         pool, ib, total, total_prot, n_free = mk.exchange_call(
             state.pool, state.inbox, h, params)
     elif state.lineage is not None:
-        # Trace ids ride the IDENTICAL slot permutation the packed rows
-        # take: movers' pool_id entries scatter into inbox_id via the
-        # core's islot, moved rows clear, and each placed/overflowed
-        # mover gets an EXCHANGE/DELIVER-reason span.  Pure observation
-        # on side arrays -- pool/inbox bytes are untouched.
-        pool, ib, total, total_prot, n_free, (islot_l, ok_l, mvp_l,
-                                              pad_l) = _exchange_core(
-            state.pool, state.inbox, h, params, ret_islot=True)
+        # Trace ids ride the IDENTICAL slot map the packed rows take:
+        # each slot that takes a mover gathers its pool_id entry into
+        # inbox_id, moved rows clear, and each placed/overflowed mover
+        # gets an EXCHANGE/DELIVER-reason span.  Pure observation on
+        # side arrays -- pool/inbox bytes are untouched.
+        pool, ib, total, total_prot, n_free, (take_l, row_l, ok_l) = \
+            _exchange_core(state.pool, state.inbox, h, params,
+                           ret_slots=True)
         ln = state.lineage
-        lpad = jnp.pad(ln.pool_id, (0, pad_l))
         state = state.replace(lineage=ln.replace(
-            inbox_id=ln.inbox_id.at[islot_l].set(lpad, mode="drop"),
+            inbox_id=jnp.where(take_l, ln.pool_id[row_l], ln.inbox_id),
             pool_id=jnp.where(moving, 0, ln.pool_id)))
-        now_p = jnp.broadcast_to(state.now, lpad.shape)
-        dst_p = jnp.pad(dst, (0, pad_l))
-        state = _lineage_append(state, ok_l, time_v=now_p, id_v=lpad,
-                                host_v=dst_p, stage=SPAN_EXCHANGE)
+        now_p = jnp.broadcast_to(state.now, (p0,))
+        state = _lineage_append(state, ok_l, time_v=now_p, id_v=ln.pool_id,
+                                host_v=dst, stage=SPAN_EXCHANGE)
         # Overflowed movers die here: shed pure ACKs vs counted drops
-        # (the two-class re-rank puts acks last exactly when drops
+        # (the two-class re-order puts acks last exactly when drops
         # exist, so a dropped pure ack under overflow IS a shed one).
         if state.inbox.blk.shape[1] >= ICOLS:
             from ..transport.tcp import pure_ack as _pure_ack_l
-            shed_l = jnp.pad(_pure_ack_l(
+            shed_l = _pure_ack_l(
                 state.pool.blk[:, ICOL_PROTO], state.pool.blk[:, ICOL_FLAGS],
-                state.pool.blk[:, ICOL_LEN]), (0, pad_l)) & mvp_l
+                state.pool.blk[:, ICOL_LEN]) & moving
         else:
-            shed_l = jnp.zeros_like(mvp_l)
+            shed_l = jnp.zeros_like(moving)
         state = _lineage_append(
-            state, mvp_l & ~ok_l, time_v=now_p, id_v=lpad, host_v=dst_p,
+            state, moving & ~ok_l, time_v=now_p, id_v=ln.pool_id, host_v=dst,
             stage=SPAN_EXCHANGE,
             reason_v=jnp.where(shed_l, LREASON_ACK_SHED, LREASON_POOL))
     else:
@@ -839,8 +878,8 @@ def _exchange_body_mesh(state: SimState, params) -> SimState:
 def _exchange(state: SimState, params, fused: bool = False) -> SimState:
     """Run the boundary exchange iff anything moved this window.
     `fused` routes the slab core through the single-block pallas call
-    (megakernel.exchange_call); the mesh body keeps the reference core
-    regardless -- its collectives cannot live inside a kernel."""
+    (megakernel.exchange_call); the mesh body keeps its own all-to-all
+    exchange regardless -- its collectives cannot live inside a kernel."""
     moving = jnp.any(state.pool.stage == STAGE_IN_FLIGHT)
     if _on_mesh(state):
         # The mesh body contains collectives, so every shard must take

@@ -257,10 +257,12 @@ def _call_blocked(body, g, shard_in, full_in):
 
 def exchange_call(pool, ib, h, params):
     """engine._exchange_core as ONE single-block pallas call: the
-    boundary exchange's ~600-op rank/splice graph (sort, two ranking
-    passes, the destination-slab scatters) collapses to a single
-    launch per window.  The destination scatter is cross-host, so the
-    exchange cannot block on hosts: every grid step sees the full
+    boundary exchange's order/deliver graph (the keyed sort of movers
+    by destination, the class-keyed re-sort of ACK-shedding windows,
+    segment bounds, the destination-side slot map and row gathers)
+    collapses to a single launch per window.  A destination slab
+    gathers its movers from any source host, so the exchange cannot
+    block on hosts: every grid step sees the full
     arrays, the work runs under `pl.when(step == 0)`, and the grid is
     2 rather than 1 because XLA's while-loop simplifier unrolls
     trip-count-1 loops -- which would dissolve the kernel region back

@@ -226,9 +226,10 @@ ICOLS = 24
 
 # Narrow inbox width for worlds whose app never opens TCP sockets: the
 # TS/TSE/SACK columns (14..23) only feed the TCP machine, and the
-# window-boundary exchange's packed row scatter is the single most
-# expensive op per window (tools/exchprof.py) -- scattering 14 columns
-# instead of 24 cuts it ~40% for pure-UDP worlds (phold).
+# window-boundary exchange moves whole packed rows into the inbox (a row
+# gather on one device, a row scatter on the mesh) -- moving 14 columns
+# instead of 24 cut the scatter ~40% for pure-UDP worlds (phold,
+# tools/exchprof.py).
 NCOLS_UDP = ICOL_CTR_HI + 1
 
 # Outbox/emission extension columns: the packed OUTBOX block (and the

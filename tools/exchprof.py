@@ -1,4 +1,5 @@
-"""Component ablation of engine._exchange_body on the real chip.
+"""Component ablation of the single-device boundary exchange
+(engine._exchange_body / _exchange_core) on the real chip.
 
     python tools/exchprof.py [num_hosts]
 """
@@ -65,66 +66,67 @@ def main():
     timeloop("exchange_body full", state, params,
              lambda s: engine._exchange_body(s, params))
 
-    # Variant bodies copied from _exchange_body with parts disabled.
-    from shadow1_tpu.core.state import (ICOLS, ICOL_TIME_LO, ICOL_TIME_HI,
+    # Variant bodies copied from _exchange_core (single-device) with
+    # parts disabled: the keyed sort, the segment bounds read off the
+    # sorted keys, the destination-side slot map, the row gather.
+    from shadow1_tpu.core.state import (ICOL_TIME_LO, ICOL_TIME_HI,
                                         enc_lo, enc_hi)
 
-    def variant(s, *, do_rank=True, do_order=True, do_scatter=True):
+    def variant(s, *, do_sort=True, do_bounds=True, do_slots=True,
+                do_gather=True):
         pool, ib, hosts = s.pool, s.inbox, s.hosts
         h = hosts.num_hosts
         p0 = pool.capacity
-        p1 = ib.capacity
-        ki = p1 // h
+        ki = ib.capacity // h
         moving = pool.stage == STAGE_IN_FLIGHT
         dst = jnp.clip(pool.dst, 0, h - 1)
-        m = engine._superblock(p0, h)
-        npad = -(-p0 // m) * m
-        pad = npad - p0
-        dstp = jnp.pad(dst, (0, pad))
-        mvp = jnp.pad(moving, (0, pad))
-        if do_rank:
-            rank, total = engine._rank_by_dst(mvp, dstp, h, m)
+        idx = jnp.arange(p0, dtype=I32)
+        key = jnp.where(moving, dst, h).astype(I32)
+        if do_sort:
+            keys, src = engine._keyed_order(key, idx)
         else:
-            rank = jnp.zeros((npad,), I32)
-            total = jnp.zeros((h,), I32)
+            keys, src = key, idx
+        if do_bounds:
+            bnd = engine._seg_starts(keys, jnp.arange(h + 1, dtype=I32))
+        else:
+            bnd = jnp.arange(h + 1, dtype=I32) * (p0 // h) + keys[0] * 0
+        start, total = bnd[:h], bnd[1:] - bnd[:h]
         free2 = (ib.stage == STAGE_FREE).reshape(h, ki)
-        ids = jnp.arange(ki, dtype=I32)[None, :]
-        if do_order:
-            order2 = jnp.argsort(jnp.where(free2, ids, ids + ki),
-                                 axis=1).astype(I32)
+        if do_slots:
+            fr = jnp.cumsum(free2, axis=1, dtype=I32) - free2
+            take = (free2 & (fr < total[:, None])).reshape(-1)
+            row = src[jnp.clip(start[:, None] + fr, 0, p0 - 1)].reshape(-1)
         else:
-            order2 = jnp.broadcast_to(ids, (h, ki)).astype(I32)
-        n_free = jnp.sum(free2, axis=1, dtype=I32)
-        within = order2.reshape(-1)[dstp * ki + jnp.clip(rank, 0, ki - 1)]
-        ok = mvp & (rank < n_free[dstp])
-        islot = jnp.where(ok, dstp * ki + within, p1)
+            take = free2.reshape(-1) & (total.sum() > 0)
+            row = jnp.arange(ib.capacity, dtype=I32) % p0 + src[0] * 0
         ic = ib.blk.shape[1]
         vals = jnp.concatenate(
             [pool.blk[:, :ICOL_TIME_LO],
              enc_lo(pool.time)[:, None], enc_hi(pool.time)[:, None],
              pool.blk[:, ICOL_TIME_HI + 1:ic]], axis=1)
-        vals = jnp.pad(vals, ((0, pad), (0, 0)))
-        if do_scatter:
+        if do_gather:
             ib = ib.replace(
-                blk=ib.blk.at[islot].set(vals, mode="drop"),
-                stage=ib.stage.at[islot].set(STAGE_IN_FLIGHT, mode="drop"),
-                status=ib.status.at[islot].set(
-                    jnp.pad(pool.status, (0, pad)), mode="drop"))
+                blk=jnp.where(take[:, None], vals[row], ib.blk),
+                stage=jnp.where(take, STAGE_IN_FLIGHT, ib.stage),
+                status=jnp.where(take, pool.status[row], ib.status))
         else:
-            # keep a data dependence on the whole islot/vals pipeline
-            ib = ib.replace(stage=ib.stage + (jnp.sum(islot) * 0) +
-                            (jnp.sum(vals[:, 0]) * 0))
+            # keep a data dependence on the whole take/row/vals pipeline
+            ib = ib.replace(stage=ib.stage + (jnp.sum(row, dtype=I32) * 0) +
+                            (jnp.sum(take, dtype=I32) * 0) +
+                            (jnp.sum(vals[:, 0], dtype=I32) * 0))
         pool = pool.replace(stage=jnp.where(moving, STAGE_FREE, pool.stage))
         return s.replace(pool=pool, inbox=ib)
 
     timeloop("variant full (sanity)", state, params,
              lambda s: variant(s))
-    timeloop("no row-scatter", state, params,
-             lambda s: variant(s, do_scatter=False))
-    timeloop("no rank (hierarchy off)", state, params,
-             lambda s: variant(s, do_rank=False))
-    timeloop("no free-order argsort", state, params,
-             lambda s: variant(s, do_order=False))
+    timeloop("no row gather", state, params,
+             lambda s: variant(s, do_gather=False))
+    timeloop("no slot map", state, params,
+             lambda s: variant(s, do_slots=False))
+    timeloop("no segment bounds", state, params,
+             lambda s: variant(s, do_bounds=False))
+    timeloop("no keyed sort", state, params,
+             lambda s: variant(s, do_sort=False))
 
 
 if __name__ == "__main__":
